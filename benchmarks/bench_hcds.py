@@ -30,8 +30,8 @@ speedups are apples-to-apples on the machine that ran the sweep:
   from the unchanged ``curve`` primitives it used.
 
 The sweep also records the AOT kernel-cache split (cold trace+compile vs
-warm blob load, per pow2 lane bucket, each measured in a fresh
-subprocess) and the ``set_backend("auto")`` calibration probe. The
+warm blob load, per pow2 lane bucket, both measured in this process) and
+the ``set_backend("auto")`` calibration probe. The
 acceptance bars: default backend ≥2× over the PR-5 batch at N=32, and a
 jax warm start (AOT hit, fresh process) under 1 s.
 """
@@ -40,8 +40,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import subprocess
-import sys
 from pathlib import Path
 from typing import Optional
 
@@ -303,33 +301,35 @@ def _pr5_batch_verify(items) -> bool:
 
 
 def _aot_cache_split(lanes) -> dict:
-    """Cold vs warm kernel start-up per pow2 lane bucket, each side in a
-    FRESH subprocess (in-process timing would hit jit/export caches):
+    """Cold vs warm kernel start-up per pow2 lane bucket, in this process
+    (a child would need the device this process already holds):
 
-    * ``cold`` — ``aotcache --warm``: trace + export + XLA compile where
-      no blob exists yet; a bucket already on disk reports
+    * ``cold`` — the first ``warm_bucket``: trace + export + XLA compile
+      where no blob exists yet; a bucket already on disk reports
       ``source: "aot"`` instead of a compile (its cold cost was paid on
       an earlier run).
-    * ``warm`` — ``aotcache --smoke``: blob deserialize + persistent-XLA-
-      cache hit — the start-up every later process actually pays.
+    * ``warm`` — the in-memory kernels and jit caches dropped, then
+      ``warm_bucket`` again: blob deserialize + persistent-XLA-cache hit,
+      the start-up a later process pays after its imports.
+
+    A bucket that fails raises: a sweep with a hole in it is no result.
     """
-    arg = ",".join(str(x) for x in lanes)
+    from repro.core.crypto.backends import jax as jax_backend
     out: dict = {}
-    for label, flag in (("cold", "--warm"), ("warm", "--smoke")):
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.core.crypto.aotcache",
-             flag, "--lanes", arg], capture_output=True, text=True)
-        try:
-            report = json.loads(proc.stdout)
-        except json.JSONDecodeError:
-            out[label] = {"error": proc.stderr[-500:]}
-            continue
-        out[label] = {f"l{b.get('lanes', '?')}": b
-                      for b in report["buckets"]}
-        for key, b in out[label].items():
-            if "first_call_s" in b:
-                emit(f"crypto_aot/{label}/{key}",
-                     b["first_call_s"] * 1e6, f"source={b['source']}")
+    for label in ("cold", "warm"):
+        if label == "warm":
+            jax_backend._KERNELS.clear()
+            jax_backend._COMPILED_LANE_BUCKETS.clear()
+            jax.clear_caches()
+        out[label] = {}
+        for lane_count in lanes:
+            b = jax_backend.warm_bucket(lane_count)
+            if "error" in b:
+                raise RuntimeError(
+                    f"crypto kernel l{lane_count} ({label}): {b['error']}")
+            out[label][f"l{lane_count}"] = b
+            emit(f"crypto_aot/{label}/l{lane_count}",
+                 b["first_call_s"] * 1e6, f"source={b['source']}")
     return out
 
 
@@ -340,16 +340,11 @@ def bench_crypto_backend_sweep(results: Optional[dict] = None) -> dict:
     signatures, against the in-process PR-4 (affine) and PR-5 (Jacobian
     fixed-window) reconstructions. ``jax`` rows are steady-state (the
     lane bucket's kernel warmed first); the cold-vs-warm start-up split
-    lives under ``aot``, measured in fresh subprocesses, and the
-    ``set_backend("auto")`` probe under ``calibration``.
+    lives under ``aot``, and the ``set_backend("auto")`` probe under
+    ``calibration``.
     """
-    try:
-        crypto._get_ops("jax")
-        have_jax = True
-    except Exception as e:          # jax-less installs still get the sweep
-        have_jax = False
-        emit("crypto_backends/jax", 0.0, f"unavailable: {e}")
-    aot = _aot_cache_split(CRYPTO_BATCH_SIZES) if have_jax else {}
+    crypto._get_ops("jax")
+    aot = _aot_cache_split(CRYPTO_BATCH_SIZES)
     sweep: dict = {}
     for n in CRYPTO_BATCH_SIZES:
         items = _sweep_items(n)
@@ -402,13 +397,11 @@ def bench_crypto_backend_sweep(results: Optional[dict] = None) -> dict:
                                   stat="min")
         emit(f"crypto_backends/glv/N{n}", row["glv_us"],
              f"speedup_vs_pr5={row['pr5_batch_us']/row['glv_us']:.2f}x")
-        if have_jax:
-            row["jax_warm_us"] = time_call(run_backend("jax"), repeats=3,
-                                           stat="min")
-            row["jax_speedup_vs_pr5"] = (row["pr5_batch_us"]
-                                         / row["jax_warm_us"])
-            emit(f"crypto_backends/jax/N{n}", row["jax_warm_us"],
-                 f"speedup_vs_pr5={row['jax_speedup_vs_pr5']:.2f}x")
+        row["jax_warm_us"] = time_call(run_backend("jax"), repeats=3,
+                                       stat="min")
+        row["jax_speedup_vs_pr5"] = row["pr5_batch_us"] / row["jax_warm_us"]
+        emit(f"crypto_backends/jax/N{n}", row["jax_warm_us"],
+             f"speedup_vs_pr5={row['jax_speedup_vs_pr5']:.2f}x")
         sweep[f"N{n}"] = row
     crypto.set_backend("auto")      # run + record the calibration probe
     calib = crypto.calibration_info()
@@ -418,10 +411,8 @@ def bench_crypto_backend_sweep(results: Optional[dict] = None) -> dict:
         raise RuntimeError(
             f"default backend {default!r} was not timed at N=16 — the "
             f"acceptance metric cannot be recorded against it")
-    warm16 = None
-    w16 = aot.get("warm", {}).get("l16", {})
-    if "first_call_s" in w16:
-        warm16 = w16.get("load_s", 0.0) + w16["first_call_s"]
+    w16 = aot["warm"]["l16"]
+    warm16 = w16["load_s"] + w16["first_call_s"]
     out = {
         "point_backends": sweep,
         "default_backend": default,

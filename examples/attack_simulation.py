@@ -15,7 +15,9 @@ registry"). Add your own attacks by registering a ``sim.Scenario`` with
 adversaries from ``repro.sim.adversary``.
 """
 
-from repro import sim
+from repro import compile_cache, sim
+
+compile_cache.enable()
 
 for name in ("plagiarist", "bribery_targeted", "bribery_random"):
     sc = sim.get_scenario(name)

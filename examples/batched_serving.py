@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models.model_api import Model
 from repro.serving import GenerationRequest, SamplerConfig, ServingEngine
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.8)
     ap.add_argument("--top-p", type=float, default=0.9)
     args = ap.parse_args()
+    compile_cache.enable()
 
     model = Model(get_config(args.arch).reduced())
     params = model.init(jax.random.key(0))

@@ -13,7 +13,7 @@ Run:  PYTHONPATH=src python examples/bhfl_train.py [--nodes 8] [--rounds 10]
 
 import argparse
 
-from repro import api
+from repro import api, compile_cache
 
 
 def main():
@@ -27,6 +27,7 @@ def main():
     ap.add_argument("--distribution", default="iid",
                     choices=["iid", "label", "dirichlet"])
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.model == "mlp":
         data = api.make_mnist_like(n_train=6000, n_test=1000)

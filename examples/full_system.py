@@ -17,7 +17,9 @@ below.
 Run:  PYTHONPATH=src python examples/full_system.py
 """
 
-from repro import api
+from repro import api, compile_cache
+
+compile_cache.enable()
 
 N_NODES = 6
 

@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/llm_pofel_round.py [--arch rwkv6-1.6b]
 
 import argparse
 
+from repro import compile_cache
 from repro.launch.train import train_reduced
 
 
@@ -17,6 +18,7 @@ def main():
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--outer", default="nesterov", choices=["sgd1", "nesterov"])
     args = ap.parse_args()
+    compile_cache.enable()
     train_reduced(args.arch, steps=args.steps, n_clusters=4, batch=8,
                   seq=64, seed=0, outer=args.outer)
 

@@ -12,8 +12,10 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 import numpy as np
 
-from repro import api
+from repro import api, compile_cache
 from repro.models.mlp import MLPConfig, mlp_init
+
+compile_cache.enable()
 
 N_NODES = 5
 

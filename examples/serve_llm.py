@@ -7,6 +7,7 @@ Run:  PYTHONPATH=src python examples/serve_llm.py [--arch zamba2-7b]
 
 import argparse
 
+from repro import compile_cache
 from repro.launch.serve import serve_reduced
 
 
@@ -15,6 +16,7 @@ def main():
     ap.add_argument("--arch", default="zamba2-7b")
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args()
+    compile_cache.enable()
     serve_reduced(args.arch, batch=args.batch, prompt_len=24, gen=12,
                   seed=0, temperature=0.0)
 

@@ -38,9 +38,10 @@ Package layout (the point-arithmetic hot loop lives below the seam):
 * ``backends.jax`` — the limb-vectorized JAX backend: field elements as
   8×32-bit limbs in uint64 lanes, the whole RLC batch equation as one
   jitted GLV multi-scalar program over all deduplicated signatures;
-* ``aotcache`` — on-disk ``jax.export`` kernel blobs + a persistent XLA
-  compilation cache, so the jax backend's multi-second compile is paid
-  once per install instead of once per process.
+* ``aotcache`` — on-disk ``jax.export`` kernel blobs, beside the
+  persistent XLA compilation cache of ``repro.compile_cache``, so the jax
+  backend's multi-second compile is paid once per install instead of once
+  per process.
 
 The Python backends run in the *host control plane* of the framework: the
 TPU training graph never hashes or signs. The ``jax`` backend moves the

@@ -9,16 +9,15 @@ cost to once per *install*:
   serialized per (kernel version, jax version, device backend, ladder
   steps, lane bucket) under :func:`cache_root`. Deserializing skips
   tracing and lowering entirely (~milliseconds).
-* **persistent XLA compilation cache** — `jax_compilation_cache_dir`
-  pointed at a sibling directory, so the backend-compile step that
+* **persistent XLA compilation cache** — owned by
+  :mod:`repro.compile_cache`, so the backend-compile step that
   `exported.call` still performs on first use is a disk hit instead of a
-  fresh ~10 s XLA run. Both layers together take a cold process to a
-  sub-second warm start (measured in BENCH_crypto.json).
+  fresh ~10 s XLA run.
 
 Cache root resolution: ``$REPRO_CRYPTO_KERNEL_CACHE`` if set, else
-``$XDG_CACHE_HOME``/``~/.cache`` + ``repro/crypto-kernels``. Entries are
-invalidated structurally by their key — a jax upgrade, device change, or
-kernel rework (bump :data:`KERNEL_VERSION`) lands in a fresh
+``crypto-kernels`` inside :func:`repro.compile_cache.cache_dir`. Entries
+are invalidated structurally by their key — a jax upgrade, device change,
+or kernel rework (bump :data:`KERNEL_VERSION`) lands in a fresh
 subdirectory; stale ones are just dead files, safe to delete wholesale.
 
 CLI (used by CI to persist the cache across workflow runs)::
@@ -48,9 +47,8 @@ def cache_root() -> Path:
     env = os.environ.get(ENV_CACHE_DIR)
     if env:
         return Path(env)
-    xdg = os.environ.get("XDG_CACHE_HOME")
-    base = Path(xdg) if xdg else Path.home() / ".cache"
-    return base / "repro" / "crypto-kernels"
+    from repro.compile_cache import cache_dir
+    return cache_dir() / "crypto-kernels"
 
 
 def _jax_tag() -> str:
@@ -62,31 +60,6 @@ def _jax_tag() -> str:
 def kernel_path(steps: int, lanes: int) -> Path:
     return (cache_root() / _jax_tag()
             / f"rlc-v{KERNEL_VERSION}-s{steps}-l{lanes}.jaxexport")
-
-
-def xla_cache_dir() -> Path:
-    return cache_root() / _jax_tag() / "xla"
-
-
-def enable_persistent_compilation_cache() -> None:
-    """Point XLA's persistent compilation cache into the kernel cache
-    root — unless the user already configured their own directory."""
-    import jax
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return
-    except AttributeError:  # pragma: no cover - much older jax
-        return
-    path = xla_cache_dir()
-    path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    # our kernels compile in seconds and are few — cache unconditionally
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # pragma: no cover - option renamed upstream
-            pass
 
 
 def load_kernel(steps: int, lanes: int) -> Optional[bytes]:

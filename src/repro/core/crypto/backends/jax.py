@@ -324,14 +324,15 @@ def _get_compiled(lanes: int, steps: int = _GLV_STEPS):
     serialized ``jax.export`` blob first — deserialization skips
     trace + lowering; a miss traces and jits, then best-effort exports
     the blob for the next process. Either way the persistent XLA
-    compilation cache (``aotcache.enable_persistent_compilation_cache``)
-    absorbs the backend-compile step across processes.
+    compilation cache (``repro.compile_cache``) absorbs the
+    backend-compile step across processes.
     """
     ent = _KERNELS.get(lanes)
     if ent is not None:
         return ent
+    from repro import compile_cache
     from .. import aotcache
-    aotcache.enable_persistent_compilation_cache()
+    compile_cache.enable()
     fn = None
     source = "jit"
     blob = aotcache.load_kernel(steps, lanes)

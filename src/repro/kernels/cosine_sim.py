@@ -10,9 +10,12 @@ passes at 2 FLOP each — the kernel is HBM-bound either way, so fusing the
 three reductions cuts HBM traffic ~3× (the hillclimb log §Perf quantifies
 this on the compiled dry-run).
 
-Tiling: grid = (N/bn, D/bd), W tiles (bn, bd) in VMEM, gw tile (1, bd)
-re-fetched per row-block (Pallas pipelines it), fp32 accumulators live in
-the output refs (revisited across the D grid dimension).
+Tiling: grid = (⌈N/bn⌉, ⌈D/bd⌉), W tiles (bn, bd) in VMEM, gw tile
+(1, bd) re-fetched per row-block (Pallas pipelines it), fp32 accumulators
+live in the output refs (revisited across the D grid dimension). The
+outputs are 2-D — (N, 1) row partials in (bn, 1) blocks and a (1, 1)
+‖gw‖² — because Mosaic accepts a rank-1 block only when it spans the
+whole array or a multiple of 128 lanes, which (bn,) blocks do not.
 """
 
 from __future__ import annotations
@@ -24,7 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _cosine_partials_kernel(w_ref, g_ref, dot_ref, wsq_ref, gsq_ref):
+def _cosine_partials_kernel(w_ref, g_ref, dot_ref, wsq_ref, gsq_ref, *,
+                            d: int, bd: int):
     j = pl.program_id(1)
     i = pl.program_id(0)
 
@@ -39,12 +43,18 @@ def _cosine_partials_kernel(w_ref, g_ref, dot_ref, wsq_ref, gsq_ref):
 
     w = w_ref[...].astype(jnp.float32)          # (bn, bd)
     g = g_ref[...].astype(jnp.float32)          # (1, bd)
-    dot_ref[...] += jnp.sum(w * g, axis=1)
-    wsq_ref[...] += jnp.sum(w * w, axis=1)
+    if d % bd:
+        # the last D block runs past the array: its tail holds unspecified
+        # values, so select (not multiply) them away
+        col = j * bd + jax.lax.broadcasted_iota(jnp.int32, (1, bd), 1)
+        w = jnp.where(col < d, w, 0.0)
+        g = jnp.where(col < d, g, 0.0)
+    dot_ref[...] += jnp.sum(w * g, axis=1, keepdims=True)
+    wsq_ref[...] += jnp.sum(w * w, axis=1, keepdims=True)
 
     @pl.when(i == 0)
     def _acc_g():
-        gsq_ref[...] += jnp.sum(g * g, axis=1)
+        gsq_ref[...] += jnp.sum(g * g, axis=1, keepdims=True)
 
 
 def interpret_default() -> bool:
@@ -79,31 +89,25 @@ def _cosine_partials(W: jax.Array, gw: jax.Array, *, block_n: int = 8,
     N, D = W.shape
     bn = min(block_n, N)
     bd = min(block_d, D)
-    pad_n = (-N) % bn
-    pad_d = (-D) % bd
-    if pad_n or pad_d:
-        W = jnp.pad(W, ((0, pad_n), (0, pad_d)))
-        gw = jnp.pad(gw, (0, pad_d))
-    Np, Dp = W.shape
-    grid = (Np // bn, Dp // bd)
+    # edge blocks are partial: out-of-range columns are masked in the
+    # kernel and out-of-range rows are never written back, so W is read
+    # once, in place (no padded copy)
+    grid = (pl.cdiv(N, bn), pl.cdiv(D, bd))
+    rows = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
 
     dot, wsq, gsq = pl.pallas_call(
-        _cosine_partials_kernel,
+        functools.partial(_cosine_partials_kernel, d=D, bd=bd),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda i, j: (i, j)),
             pl.BlockSpec((1, bd), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
+        out_specs=[rows, rows, pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(W, gw.reshape(1, Dp))
-    return dot[:N], wsq[:N], gsq[0]
+    )(W, gw.reshape(1, D))
+    return dot[:, 0], wsq[:, 0], gsq[0, 0]

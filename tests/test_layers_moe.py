@@ -120,8 +120,8 @@ def test_mamba_sharded_flag_numerically_identical():
     cfg = Mamba2Config(d_model=32, d_state=8, expand=2, head_dim=8)
     params = mamba2_init(cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 20, 32))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
+    from repro.launch.mesh import make_host_mesh
+    with make_host_mesh():
         o1, _ = mamba2_apply(params, x, cfg, sharded=False)
         o2, _ = mamba2_apply(params, x, cfg, sharded=True)
     np.testing.assert_allclose(np.asarray(o1, np.float32),

@@ -76,11 +76,12 @@ MINI_DRYRUN = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.fl import pofel_trainer as pt
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import build_train_setup
     from repro.configs.shapes import InputShape
     from repro.models.transformer import FwdOptions
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     shape = InputShape("mini_train", 64, 8, "train")
     profile = "{profile}"
     if profile == "zero3":
