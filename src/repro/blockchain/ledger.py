@@ -31,6 +31,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 from repro.blockchain.block import GENESIS_HASH, Block, block_hash
 from repro.core import crypto
 from repro.core.envelope import verify_envelopes
+from repro.obs import spanned
 
 
 class InvalidBlock(ValueError):
@@ -61,6 +62,7 @@ class Ledger:
     def height(self) -> int:
         return len(self.blocks)
 
+    @spanned("ledger.append", cat="ledger")
     def append(self, block: Block, leader_pk: Optional[crypto.Point] = None,
                retally: Optional[Callable[[Block], int]] = None) -> None:
         if block.prev_hash != self.head_hash:

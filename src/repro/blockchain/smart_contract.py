@@ -27,6 +27,7 @@ import numpy as np
 from repro.core import crypto
 from repro.core.btsv import BTSVConfig, BTSVResult, btsv_round, init_history
 from repro.core.envelope import SignedEnvelope, verify_envelopes
+from repro.obs import spanned
 
 
 def vote_payload_digest(node_id: int, round: int, vote: int,
@@ -85,6 +86,7 @@ class VoteTallyContract:
         # attribution (forged envelope / missing signature)
         self.rejected_votes: Dict[int, Dict[int, str]] = {}
 
+    @spanned("btsv.submit", cat="btsv")
     def submit(self, s: VoteSubmission) -> None:
         if not (0 <= s.node_id < self.n_nodes):
             raise ContractError(f"unknown node {s.node_id}")
@@ -137,6 +139,7 @@ class VoteTallyContract:
             self.rejected_votes.setdefault(round, {}).update(rejected)
         return {i: s for i, s in subs.items() if i not in rejected}
 
+    @spanned("btsv.tally", cat="btsv")
     def tally(self, round: int,
               min_submissions: Optional[int] = None) -> BTSVResult:
         """Execute Alg. 4 once enough submissions for ``round`` are in.

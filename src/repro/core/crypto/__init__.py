@@ -66,7 +66,7 @@ from repro.core.crypto import curve, field
 from repro.core.crypto.backends.python import (BatchOps, CurveOps, GLVOps,
                                                NaiveOps, WindowedOps,
                                                rlc_coefficient)
-from repro.obs import get_recorder
+from repro.obs import get_recorder, spanned
 
 # ---------------------------------------------------------------------------
 # Back-compat re-exports: the pre-package module exposed these names, and
@@ -257,6 +257,16 @@ def _calibrate(probe_n: int = 16, force: bool = False) -> str:
 
 def sha256_digest(*parts: bytes) -> bytes:
     """H(part0 || part1 || ...) — the commitment digest of Alg. 2 line 2."""
+    rec = get_recorder()
+    if not rec.enabled:
+        return _sha256(parts)
+    n = sum(len(part) for part in parts)
+    rec.counter("crypto.sha256_bytes", n)
+    with rec.span("crypto.sha256", cat="crypto", bytes=n):
+        return _sha256(parts)
+
+
+def _sha256(parts: Sequence[bytes]) -> bytes:
     h = hashlib.sha256()
     for part in parts:
         h.update(part)
@@ -367,6 +377,7 @@ class Signature(NamedTuple):
         raise TypeError(f"cannot coerce {type(tag).__name__} to Signature")
 
 
+@spanned("crypto.sign", cat="crypto")
 def dsign(digest: bytes, private_key: int) -> Signature:
     """DSign(d, SK) → tag (Alg. 2 line 3).
 
@@ -461,7 +472,6 @@ def verify_batch(items: Sequence[BatchItem],
     if not rec.enabled:
         return _verify_batch_impl(items, backend)
     name = backend if backend is not None else _BACKEND
-    t0 = time.perf_counter()
     with rec.span("crypto.verify_batch", cat="crypto",
                   backend=name, items=len(items)):
         result = _verify_batch_impl(items, backend)
@@ -469,9 +479,6 @@ def verify_batch(items: Sequence[BatchItem],
     rec.counter("crypto.verify_batch_items", len(items))
     if result.bad:
         rec.counter("crypto.verify_batch_forged", len(result.bad))
-    rec.observe("crypto.verify_batch_ms",
-                (time.perf_counter() - t0) * 1e3)
-    rec.observe("crypto.verify_batch_size", len(items))
     return result
 
 

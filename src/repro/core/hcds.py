@@ -305,24 +305,28 @@ def run_hcds_round(nodes: list[HCDSNode], models: list[Any], round: int,
             forged = batch.bad_senders([c.envelope for c in commits])
             raise RuntimeError(f"honest commit rejected: forged envelope from "
                                f"node(s) {forged}")
-        for c in commits:
-            for n in nodes:
-                if n.node_id != c.node_id:
-                    res = n.receive_commit(c, pks[c.node_id], verified=True)
-                    if not res.accepted:
-                        raise RuntimeError(
-                            f"honest commit rejected: {c.node_id}->{n.node_id}: {res.reason}")
-        for n in nodes:                 # the commit/reveal barrier (Alg. 2)
-            n.finalize_commit_stage(round)
+        with rec.span("hcds.receive", cat="hcds", stage="commit"):
+            for c in commits:
+                for n in nodes:
+                    if n.node_id != c.node_id:
+                        res = n.receive_commit(c, pks[c.node_id],
+                                               verified=True)
+                        if not res.accepted:
+                            raise RuntimeError(
+                                f"honest commit rejected: {c.node_id}->"
+                                f"{n.node_id}: {res.reason}")
+            for n in nodes:             # the commit/reveal barrier (Alg. 2)
+                n.finalize_commit_stage(round)
     with rec.span("hcds:reveal_stage", cat="hcds", round=round,
                   n_nodes=len(nodes)):
         reveals = [n.reveal(round) for n in nodes]
         digests = {r.node_id: crypto.sha256_digest(r.nonce, r.model_bytes)
                    for r in reveals}
         out: dict[int, dict[int, HCDSResult]] = {n.node_id: {} for n in nodes}
-        for r in reveals:
-            for n in nodes:
-                if n.node_id != r.node_id:
-                    out[n.node_id][r.node_id] = n.receive_reveal(
-                        r, pks[r.node_id], digest=digests[r.node_id])
+        with rec.span("hcds.receive", cat="hcds", stage="reveal"):
+            for r in reveals:
+                for n in nodes:
+                    if n.node_id != r.node_id:
+                        out[n.node_id][r.node_id] = n.receive_reveal(
+                            r, pks[r.node_id], digest=digests[r.node_id])
     return out

@@ -49,7 +49,7 @@ from repro.core.hcds import HCDSNode, run_hcds_round
 from repro.core.model_eval import (MEResult, make_predictions,
                                    model_evaluation_pytrees)
 from repro.core.serialization import serialize_pytree
-from repro.obs import get_recorder
+from repro.obs import device_nbytes, device_wait, get_recorder, spanned
 
 # (node_id, honest_vote, honest_predictions) -> (vote, predictions)
 VoteHook = Callable[[int, int, np.ndarray], tuple[int, np.ndarray]]
@@ -62,6 +62,7 @@ class QuorumNotReached(RuntimeError):
     complete (liveness gap). The driver should skip to the next round."""
 
 
+@spanned("me.predictions", cat="me")
 def honest_predictions(n: int, vote: int, g_max: float) -> np.ndarray:
     """An honest voter's prediction row, as a writable numpy array for the
     host-side vote path. Delegates to :func:`model_eval.make_predictions`
@@ -156,6 +157,9 @@ class CommitReveal(ConsensusPhase):
         self.public_keys = public_keys
 
     def run(self, ctx: RoundContext) -> None:
+        # the host's first read of W(k): under tracing, the FEL program's
+        # device time lands here rather than inside the first serialize
+        device_wait("W", ctx.models)
         # serialize each model once; HCDS commits and the block's model
         # digests (BlockMint) both reuse these bytes
         model_bytes = [serialize_pytree(m) for m in ctx.models]
@@ -357,8 +361,9 @@ class ModelEvaluation(ConsensusPhase):
                 raise QuorumNotReached(
                     f"round {ctx.round}: available models carry zero "
                     f"aggregate data weight")
-        ctx.evaluation = model_evaluation_pytrees(
-            list(ctx.models), sizes, g_max=ctx.g_max)
+        with get_recorder().span("me.dispatch", cat="me"):
+            ctx.evaluation = model_evaluation_pytrees(
+                list(ctx.models), sizes, g_max=ctx.g_max)
 
 
 class VoteCollection(ConsensusPhase):
@@ -398,6 +403,8 @@ class VoteCollection(ConsensusPhase):
         if ctx.evaluation is None:
             raise RuntimeError("VoteCollection requires a prior ModelEvaluation")
         n = ctx.n_nodes
+        device_wait("me", (ctx.evaluation.similarities,
+                           ctx.evaluation.global_model))
         sims = np.asarray(ctx.evaluation.similarities)
         if ctx.env is not None:
             self._run_networked(ctx, sims)
@@ -551,6 +558,7 @@ class BlockMint(ConsensusPhase):
             ledger.append(block, leader_pk=None, retally=retally)
         ctx.block = block
 
+    @spanned("block.build", cat="blockchain")
     def _mint(self, ctx: RoundContext, leader: int,
               votes: Dict[int, int]) -> Block:
         n = ctx.n_nodes
@@ -563,8 +571,12 @@ class BlockMint(ConsensusPhase):
         avail = ctx.available if ctx.available is not None else list(range(n))
         model_digests = {i: crypto.sha256_digest(model_bytes[i]).hex()
                          for i in avail}
-        gw_digest = crypto.sha256_digest(
-            np.asarray(ctx.global_model, np.float32).tobytes()).hex()
+        rec = get_recorder()
+        with rec.span("device.get", on="gw") as pull:
+            gw_bytes = np.asarray(ctx.global_model, np.float32).tobytes()
+            if rec.enabled:
+                pull.set(d2h_bytes=device_nbytes(ctx.evaluation.global_model))
+        gw_digest = crypto.sha256_digest(gw_bytes).hex()
         extra: Dict[str, Any] = {
             "rejected": {str(i): r for i, r in ctx.rejected.items()}}
         if ctx.available is not None:

@@ -48,7 +48,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 from repro.blockchain.ledger import (InvalidBlock, Ledger, _block_from_dict,
                                      _block_to_dict)
 from repro.core import crypto
-from repro.obs import get_recorder
+from repro.obs import get_recorder, spanned
 
 
 class WALConflict(RuntimeError):
@@ -143,6 +143,7 @@ class NodeWAL:
                                      dict(data)), write=True)
 
     # -- typed helpers for the four protocol statements ----------------------
+    @spanned("wal.log", cat="recovery", kind="commit")
     def log_commit(self, round: int, model_bytes: bytes, nonce: bytes,
                    digest: bytes, tag: crypto.Signature) -> WALRecord:
         """Record a commit-sent: keyed by the *model* digest (two commits
@@ -170,15 +171,19 @@ class NodeWAL:
                 f"conflicting re-commit")
         return rec
 
+    @spanned("wal.log", cat="recovery", kind="reveal")
     def log_reveal(self, round: int, digest: bytes) -> WALRecord:
         return self.append("reveal", round, digest.hex())
 
+    @spanned("wal.log", cat="recovery", kind="vote")
     def log_vote(self, round: int, vote: int) -> WALRecord:
         return self.append("vote", round, str(int(vote)))
 
+    @spanned("wal.log", cat="recovery", kind="block")
     def log_block(self, round: int, block_hash_hex: str) -> WALRecord:
         return self.append("block", round, block_hash_hex)
 
+    @spanned("wal.log", cat="recovery", kind="checkpoint")
     def log_checkpoint(self, epoch: int, statement_digest_hex: str,
                        ) -> WALRecord:
         """Record a checkpoint countersignature (keyed by epoch): a member

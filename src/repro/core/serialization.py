@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import device_nbytes, get_recorder
+
 _MAGIC = b"RPR0"
 
 
@@ -92,6 +94,16 @@ def serialize_pytree(tree: Any) -> bytes:
     Layout: MAGIC | n_leaves | for each leaf (sorted by keypath):
     len(path) path | len(dtype) dtype | ndim shape... | nbytes raw-bytes.
     """
+    rec = get_recorder()
+    if not rec.enabled:
+        return _serialize(tree)
+    # the pull of every device leaf to the host, and the packing
+    with rec.span("serialize", cat="serialization",
+                  d2h_bytes=device_nbytes(tree)):
+        return _serialize(tree)
+
+
+def _serialize(tree: Any) -> bytes:
     leaves = _sorted_leaves(tree)
     out = [_MAGIC, struct.pack("<I", len(leaves))]
     for path, leaf in leaves:

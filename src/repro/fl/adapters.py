@@ -33,6 +33,7 @@ from repro.fl.client import Client
 from repro.models.config import ArchConfig
 from repro.models.mlp import MLPConfig, mlp_accuracy, mlp_init, mlp_loss
 from repro.models.model_api import Model
+from repro.obs import device_wait, get_recorder
 from repro.optim.sgd import sgd_init, sgd_update
 
 
@@ -130,11 +131,17 @@ class MLPAdapter(_SerializationFlatten):
                            seed=seed)
 
     def evaluate(self, params: Any, dataset: Any) -> EvalResult:
-        x = jnp.asarray(dataset.x)
-        y = jnp.asarray(dataset.y)
-        return EvalResult(
-            float(mlp_accuracy(params, x, y, cfg=self.cfg)),
-            float(mlp_loss(params, x, y, cfg=self.cfg)))
+        rec = get_recorder()
+        with rec.span("device.put", on="test_set") as put:
+            x = jnp.asarray(dataset.x)
+            y = jnp.asarray(dataset.y)
+            if rec.enabled:
+                put.set(h2d_bytes=sum(a.nbytes for a in (dataset.x, dataset.y)
+                                      if not isinstance(a, jax.Array)))
+        acc = mlp_accuracy(params, x, y, cfg=self.cfg)
+        loss = mlp_loss(params, x, y, cfg=self.cfg)
+        device_wait("eval", (acc, loss))
+        return EvalResult(float(acc), float(loss))
 
     def batched_train_spec(self):
         """Batched in-graph FEL support (``repro.fl.batched_fel``).

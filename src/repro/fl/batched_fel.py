@@ -44,7 +44,6 @@ matching shapes share compiles either way via the module cache.
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Any, Callable, List, Optional, Tuple
@@ -265,37 +264,41 @@ class BatchedFELEngine:
                             t += 1
         return idx, seeds
 
-    def run_round(self, global_flat: jax.Array, round_seed: int) -> jax.Array:
-        """One FEL phase: (D,) global model → stacked (N, D) W(k), all on
-        device; one compiled-program dispatch."""
+    def _prep(self, round_seed: int) -> tuple[jax.Array, jax.Array]:
+        """The round's batch plan, on the device."""
         idx, seeds = self._batch_plan(round_seed)
         i32 = np.iinfo(np.int32)
         if np.any(seeds > i32.max) or np.any(seeds < i32.min):
             raise ValueError(
                 f"per-client seed overflows int32 (round_seed={round_seed}); "
                 "keep cfg.seed * 1000 + rounds within int32 range")
+        return jnp.asarray(idx), jnp.asarray(seeds.astype(np.int32))
+
+    def run_round(self, global_flat: jax.Array, round_seed: int) -> jax.Array:
+        """One FEL phase: (D,) global model → stacked (N, D) W(k), all on
+        device; one compiled-program dispatch."""
         rec = get_recorder()
         if not rec.enabled:
-            return self._round_fn(jnp.asarray(global_flat),
-                                  jnp.asarray(idx),
-                                  jnp.asarray(seeds, jnp.int32),
+            idx, seeds = self._prep(round_seed)
+            return self._round_fn(jnp.asarray(global_flat), idx, seeds,
                                   self._data, self._sizes_f, self._bs_dev,
                                   self._stepmask, self._template)
+        # the host's share: the batch plan and its upload
+        with rec.span("fel.prep", cat="fel") as prep:
+            idx, seeds = self._prep(round_seed)
+            prep.set(h2d_bytes=idx.nbytes + seeds.nbytes)
         # dispatch only — jax execution is async, so this span measures
-        # trace/compile + program launch, not device runtime; ``compiled``
-        # marks dispatches that traced a fresh program (the jit-compile
-        # half of the compile-vs-execute split)
+        # trace/compile + program launch, not device runtime (that is the
+        # device.wait where the host first reads W); ``compiled`` marks
+        # dispatches that traced a fresh program (the jit-compile half of
+        # the compile-vs-execute split)
         traces_before = _TRACE_COUNT[0]
-        t0 = time.perf_counter()
         rec.open_span("fel.dispatch", cat="fel")
-        W = self._round_fn(jnp.asarray(global_flat),
-                           jnp.asarray(idx),
-                           jnp.asarray(seeds, jnp.int32),
+        W = self._round_fn(jnp.asarray(global_flat), idx, seeds,
                            self._data, self._sizes_f, self._bs_dev,
                            self._stepmask, self._template)
         rec.close_span(compiled=_TRACE_COUNT[0] > traces_before)
         rec.counter("fel.dispatches")
-        rec.observe("fel.dispatch_ms", (time.perf_counter() - t0) * 1e3)
         return W
 
 

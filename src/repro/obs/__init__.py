@@ -1,9 +1,10 @@
 """repro.obs — dual-clock tracing, metrics, and round profiling.
 
 A span-based tracer threaded through the whole stack: consensus phases
-(via the ``add_phase_hook`` seam), network exchanges, crypto batch
-verification, FEL dispatch, and WAL recovery all report into one
-process-wide :class:`Recorder`. The default recorder is a no-op — the
+(via the ``add_phase_hook`` seam), network exchanges, crypto (hashing,
+signing, batch verification), serialisation, the ledger, FEL prep and
+dispatch, the host's waits on the device, and WAL recovery all report
+into one process-wide :class:`Recorder`. The default recorder is a no-op — the
 disabled path stores nothing and adds zero protocol state — and a
 :class:`TraceRecorder` scoped with :func:`use_recorder` captures
 everything inside its block.
@@ -12,6 +13,8 @@ See OBSERVABILITY.md for the span model, clock domains, and exporter
 formats; ``python -m repro.obs summarize --help`` for the CLI.
 """
 
+from repro.obs.device import device_nbytes
+from repro.obs.device import wait as device_wait
 from repro.obs.events import SECURITY_EVENTS, ObsEvent, validate_security_event
 from repro.obs.export import (chrome_trace, events_jsonl, write_chrome_trace,
                               write_events_jsonl)
@@ -20,10 +23,12 @@ from repro.obs.profile import (critical_paths, events_to_trace, format_summary,
                                load_trace, phase_percentiles)
 from repro.obs.recorder import (NullRecorder, Recorder, TraceRecorder,
                                 get_recorder, phase_span_after,
-                                phase_span_before, set_recorder, use_recorder)
+                                phase_span_before, set_recorder, spanned,
+                                use_recorder)
 from repro.obs.spans import SpanRecord, sim_now
 
 __all__ = [
+    "device_nbytes", "device_wait",
     "SECURITY_EVENTS", "ObsEvent", "validate_security_event",
     "chrome_trace", "events_jsonl", "write_chrome_trace",
     "write_events_jsonl",
@@ -31,6 +36,7 @@ __all__ = [
     "critical_paths", "events_to_trace", "format_summary", "load_trace",
     "phase_percentiles",
     "NullRecorder", "Recorder", "TraceRecorder", "get_recorder",
-    "phase_span_after", "phase_span_before", "set_recorder", "use_recorder",
+    "phase_span_after", "phase_span_before", "set_recorder", "spanned",
+    "use_recorder",
     "SpanRecord", "sim_now",
 ]

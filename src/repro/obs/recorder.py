@@ -13,7 +13,10 @@ Two implementations share one interface:
   events get a monotonically increasing ``seq`` at emission. Every
   emission site sits on a seeded deterministic code path, so the event
   stream replays byte-identically for a seed (pinned by
-  ``tests/test_determinism_smoke.py``).
+  ``tests/test_determinism_smoke.py``). Each span it opens is mirrored
+  as a ``jax.profiler.TraceAnnotation`` of the same name carrying its
+  ``span_id`` (and ``round``), so a ``jax.profiler`` trace holds the
+  program's spans on the profiler's own clock, beside the device ops.
 
 The active recorder is module state, swapped with
 :func:`set_recorder`/:func:`use_recorder`. Instrumented modules call
@@ -28,9 +31,12 @@ context they are handed (enforced statically by analysis rule RA151).
 
 from __future__ import annotations
 
+import functools
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional, TypeVar
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.events import ObsEvent, validate_security_event
 from repro.obs.metrics import MetricsRegistry
@@ -45,6 +51,9 @@ class _NoopSpan:
 
     def __enter__(self) -> "_NoopSpan":
         return self
+
+    def set(self, **attrs: Any) -> None:
+        pass
 
     def __exit__(self, *exc: Any) -> bool:
         return False
@@ -103,22 +112,35 @@ class NullRecorder(Recorder):
 
 
 class _SpanCM:
-    """Context-manager wrapper over open_span/close_span for one span."""
+    """Context-manager wrapper over open_span/close_span for one span;
+    ``set(**attrs)`` inside the block adds attrs known only once the work
+    ran (a byte count, say)."""
 
-    __slots__ = ("_rec", "_name", "_kw")
+    __slots__ = ("_rec", "_name", "_kw", "_attrs")
 
     def __init__(self, rec: "TraceRecorder", name: str, kw: Dict[str, Any]):
         self._rec = rec
         self._name = name
         self._kw = kw
+        self._attrs: Dict[str, Any] = {}
 
-    def __enter__(self) -> "TraceRecorder":
+    def __enter__(self) -> "_SpanCM":
         self._rec.open_span(self._name, **self._kw)
-        return self._rec
+        return self
+
+    def set(self, **attrs: Any) -> None:
+        self._attrs.update(attrs)
 
     def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
-        self._rec.close_span(error=et.__name__ if et is not None else None)
+        self._rec.close_span(error=et.__name__ if et is not None else None,
+                             **self._attrs)
         return False
+
+
+#: span attrs that the recorder also adds up as counters: the bytes a span
+#: copied between host and device
+_TRANSFER_COUNTERS = (("d2h_bytes", "transfer.d2h_bytes"),
+                      ("h2d_bytes", "transfer.h2d_bytes"))
 
 
 class TraceRecorder(Recorder):
@@ -150,9 +172,17 @@ class TraceRecorder(Recorder):
         if start_sim is None and sim_env is not None:
             start_sim = _env_sim_now(sim_env)
         parent = self._stack[-1].span_id if self._stack else None
-        span = _OpenSpan(self._next_span_id, name, cat, round, node, parent,
+        span_id = self._next_span_id
+        span = _OpenSpan(span_id, name, cat, round, node, parent,
                          len(self._stack), time.perf_counter(), start_sim,
                          sim_env, dict(attrs))
+        # opened after the clock read and closed before the closing one,
+        # so the profiler's event lies inside the span's wall window
+        if round is None:
+            span.mirror = TraceAnnotation(name, span_id=span_id)
+        else:
+            span.mirror = TraceAnnotation(name, span_id=span_id, round=round)
+        span.mirror.__enter__()
         self._next_span_id += 1
         self._stack.append(span)
 
@@ -161,6 +191,7 @@ class TraceRecorder(Recorder):
         if not self._stack:
             return      # tolerate an unmatched close rather than raise
         open_span = self._stack.pop()
+        open_span.mirror.__exit__(None, None, None)
         end_sim = sim_now
         if end_sim is None and open_span.sim_env is not None:
             end_sim = _env_sim_now(open_span.sim_env)
@@ -168,6 +199,9 @@ class TraceRecorder(Recorder):
         if attrs:
             merged = dict(merged)
             merged.update(attrs)
+        for attr, counter in _TRANSFER_COUNTERS:
+            if attr in merged:
+                self.metrics.counter(counter, merged[attr])
         self.spans.append(SpanRecord(
             span_id=open_span.span_id, name=open_span.name,
             cat=open_span.cat, round=open_span.round, node=open_span.node,
@@ -236,6 +270,24 @@ def use_recorder(rec: Recorder) -> Iterator[Recorder]:
         yield rec
     finally:
         set_recorder(prev)
+
+
+F = TypeVar("F", bound=Callable[..., Any])
+
+
+def spanned(name: str, cat: str = "obs", **attrs: Any) -> Callable[[F], F]:
+    """Decorator: each call of the function is a span ``name`` while a
+    recorder is enabled; disabled, the call costs one recorder read."""
+    def wrap(fn: F) -> F:
+        @functools.wraps(fn)
+        def call(*args: Any, **kwargs: Any) -> Any:
+            rec = _ACTIVE
+            if not rec.enabled:
+                return fn(*args, **kwargs)
+            with rec.span(name, cat=cat, **attrs):
+                return fn(*args, **kwargs)
+        return call  # type: ignore[return-value]
+    return wrap
 
 
 # ---------------------------------------------------------------------------

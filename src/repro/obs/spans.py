@@ -18,6 +18,12 @@ to say "22% of this round was commit-reveal retransmission stalls"
 sequence (sim) — the two domains never mix, so tracing cannot
 reintroduce the RA1xx nondeterminism class.
 
+A :class:`~repro.obs.recorder.TraceRecorder` also mirrors each span into a
+``jax.profiler`` trace, when one is running, as an annotation of the same
+name carrying the span's ``span_id``: that copy lies on the profiler's
+clock (CLOCK_REALTIME), beside the device ops, inside the span's wall
+window.
+
 Spans nest on a stack per recorder: ``parent`` is the ``span_id`` of the
 span that was open when this one opened (None for a top-level span such
 as a BHFL round), ``depth`` its nesting depth. Exporters and the
@@ -62,7 +68,8 @@ class _OpenSpan:
     """Stack entry for a span that has been opened but not yet closed."""
 
     __slots__ = ("span_id", "name", "cat", "round", "node", "parent",
-                 "depth", "wall_start", "sim_start", "sim_env", "attrs")
+                 "depth", "wall_start", "sim_start", "sim_env", "attrs",
+                 "mirror")
 
     def __init__(self, span_id: int, name: str, cat: str,
                  round: Optional[int], node: Optional[int],
@@ -80,6 +87,7 @@ class _OpenSpan:
         self.sim_start = sim_start
         self.sim_env = sim_env
         self.attrs = attrs
+        self.mirror: Any = None     # the span's profiler annotation
 
 
 def sim_now(env: Optional[Any]) -> Optional[float]:

@@ -290,3 +290,229 @@ def test_noop_recorder_changes_no_round_outputs():
     assert off.obs is None and on.obs is not None
     assert len([s for s in rec.spans if s.name == "round"]) == 2
     assert on.obs["counters"].get("recovery.wal_appends", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# spans inside the round: attrs at close, transfers, the device's edges
+# ---------------------------------------------------------------------------
+
+def test_span_cm_set_adds_attrs_and_transfers_are_counted():
+    rec = obs.TraceRecorder()
+    with rec.span("device.put", on="test_set") as put:
+        put.set(h2d_bytes=100)
+    with rec.span("serialize", d2h_bytes=7):
+        pass
+    rec.open_span("device.get", on="gw")
+    rec.close_span(d2h_bytes=5)
+    assert _span(rec, "device.put").attrs == {"on": "test_set",
+                                              "h2d_bytes": 100}
+    counters = rec.metrics_snapshot()["counters"]
+    assert counters["transfer.h2d_bytes"] == 100
+    assert counters["transfer.d2h_bytes"] == 12
+    with obs.NullRecorder().span("device.put") as put:
+        put.set(h2d_bytes=1)         # the disabled path takes it too
+
+
+def test_spanned_decorator_records_only_while_enabled():
+    @obs.spanned("work.item", cat="t", kind="k")
+    def add_one(x):
+        return x + 1
+
+    assert add_one(1) == 2
+    rec = obs.TraceRecorder()
+    with obs.use_recorder(rec):
+        assert add_one(x=2) == 3
+    assert [(s.name, s.cat, s.attrs) for s in rec.spans] == [
+        ("work.item", "t", {"kind": "k"})]
+    assert add_one.__name__ == "add_one"
+
+
+def test_device_wait_is_a_span_only_while_tracing():
+    import jax.numpy as jnp
+    import numpy as np
+    x = jnp.ones(4) * 2
+    tree = {"a": x, "b": np.ones(2)}
+    obs.device_wait("x", tree)        # disabled: nothing to record into
+    rec = obs.TraceRecorder()
+    with obs.use_recorder(rec):
+        obs.device_wait("x", tree)
+    assert [(s.name, s.attrs) for s in rec.spans] == [("device.wait",
+                                                       {"on": "x"})]
+    # only device arrays count as bytes a pull would move
+    assert obs.device_nbytes(tree) == 16
+
+
+def test_crypto_spans_carry_sizes_and_count_hashed_bytes():
+    from repro.core import crypto
+    kp = crypto.ECDSAKeyPair.generate(seed=b"k")
+    rec = obs.TraceRecorder()
+    with obs.use_recorder(rec):
+        d = crypto.sha256_digest(b"ab", b"cde")
+        crypto.dsign(d, kp.private_key)
+    assert _span(rec, "crypto.sha256").attrs == {"bytes": 5}
+    assert _span(rec, "crypto.sign").cat == "crypto"
+    assert rec.metrics_snapshot()["counters"]["crypto.sha256_bytes"] == 5
+    # the traced and the untraced path give the same digest and tag
+    assert d == crypto.sha256_digest(b"abcde")
+
+
+def _xplane_span_events(folder):
+    """(start, end, name, stats) of the host events that carry a
+    ``span_id``, in CLOCK_REALTIME ns, from the one xplane in ``folder``."""
+    from pathlib import Path
+    from jax.profiler import ProfileData
+    path = sorted(Path(folder).rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    start = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+                 if p.name == "Task Environment")
+    out = []
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "span_id" in stats:
+                    s = start + int(e.start_ns)
+                    out.append((s, s + int(e.duration_ns), e.name, stats))
+    return out
+
+
+def test_mirrored_annotations_lie_inside_their_spans(tmp_path):
+    """A TraceRecorder mirrors each span as a profiler annotation of the
+    same name that carries its span_id (and round): on the profiler's
+    clock (CLOCK_REALTIME), each lies inside its span's wall window."""
+    import time
+
+    import jax
+
+    from repro.core import crypto
+    anchor_ns, anchor_s = time.time_ns(), time.perf_counter()
+    rec = obs.TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        crypto.sha256_digest(b"untraced")   # NullRecorder: mirrors nothing
+        with obs.use_recorder(rec):
+            rec.open_span("round", cat="runtime", round=4)
+            with rec.span("fel.prep", cat="fel"):
+                time.sleep(0.002)
+            for _ in range(2):
+                crypto.sha256_digest(bytes(1 << 20))
+            time.sleep(0.002)
+            rec.close_span()
+    finally:
+        jax.profiler.stop_trace()
+    events = _xplane_span_events(tmp_path)
+    by_id = {s.span_id: s for s in rec.spans}
+    assert sorted(st["span_id"] for *_, st in events) == sorted(by_id)
+    tol = 50_000                          # ns: the two clocks' read skew
+    for start, end, name, stats in events:
+        span = by_id[stats["span_id"]]
+        assert name == span.name
+        assert stats.get("round") == span.round
+        lo = anchor_ns + (span.wall_start - anchor_s) * 1e9
+        hi = lo + span.wall_dur * 1e9
+        assert lo - tol <= start <= end <= hi + tol, (name, start - lo,
+                                                      hi - end)
+    assert _span(rec, "round").round == 4
+
+
+def _ancestors(rec, span):
+    by_id = {s.span_id: s for s in rec.spans}
+    out = []
+    while span.parent is not None:
+        span = by_id[span.parent]
+        out.append(span.name)
+    return out
+
+
+def test_round_work_spans_nest_under_their_phases_and_count_bytes():
+    """One batched BHFL round under a TraceRecorder: each new span sits
+    under the phase or runtime span that contains the work, and the bytes
+    each copy moves are on its span and in the run's counters."""
+    import numpy as np
+    data = api.make_mnist_like(n_train=300, n_test=60)
+    rec = obs.TraceRecorder("round")
+    with obs.use_recorder(rec):
+        run = api.run_bhfl(model="mlp", n_nodes=3, clients_per_node=2,
+                           fel_iterations=1, rounds=1, engine="batched",
+                           data=data)
+    assert run.runtime.engine == "batched"
+
+    def named(name, **attrs):
+        return [s for s in rec.spans if s.name == name
+                and all(s.attrs.get(k) == v for k, v in attrs.items())]
+
+    parent_of = {s.span_id: s.name for s in rec.spans}
+    (wait_w,) = named("device.wait", on="W")
+    assert parent_of[wait_w.parent] == "phase:commit_reveal"
+    (wait_me,) = named("device.wait", on="me")
+    assert parent_of[wait_me.parent] == "phase:vote_collection"
+    (wait_eval,) = named("device.wait", on="eval")
+    assert parent_of[wait_eval.parent] == "evaluate"
+    (prep,) = named("fel.prep")
+    assert parent_of[prep.parent] == "fel"
+    signs = {p for s in named("crypto.sign") for p in _ancestors(rec, s)}
+    assert {"phase:commit_reveal", "phase:vote_collection",
+            "phase:block_mint"} <= signs
+    for name, phase in (("btsv.tally", "phase:tally"),
+                        ("ledger.append", "phase:block_mint"),
+                        ("block.build", "phase:block_mint"),
+                        ("me.dispatch", "phase:model_evaluation"),
+                        ("me.predictions", "phase:vote_collection"),
+                        ("serialize", "phase:commit_reveal")):
+        assert named(name) and all(phase in _ancestors(rec, s)
+                                   for s in named(name)), name
+    (gw,) = named("device.get", on="gw")
+    assert parent_of[gw.parent] == "block.build"
+
+    n, d = 3, run.runtime._global_flat.size
+    rows = named("serialize")
+    assert len(rows) == n
+    assert sum(s.attrs["d2h_bytes"] for s in rows) == n * d * 4
+    assert gw.attrs["d2h_bytes"] == d * 4
+    test_bytes = np.asarray(data[1].x).nbytes + np.asarray(data[1].y).nbytes
+    (put,) = named("device.put", on="test_set")
+    assert put.attrs["h2d_bytes"] == test_bytes
+    eng = run.runtime._engine
+    plan = 4 * eng.fel_iterations * eng.n_clusters * eng.n_clients_padded
+    assert prep.attrs["h2d_bytes"] == plan * (
+        eng.steps_per_iteration * eng.batch_pad + 1)
+    counters = run.obs["counters"]
+    assert counters["transfer.d2h_bytes"] == (n + 1) * d * 4
+    assert counters["transfer.h2d_bytes"] == (test_bytes
+                                              + prep.attrs["h2d_bytes"])
+    assert counters["crypto.sha256_bytes"] >= 4 * n * d * 4
+
+
+# ---------------------------------------------------------------------------
+# the device programs' module names, which the benchmark reads by prefix
+# ---------------------------------------------------------------------------
+
+def test_device_module_names_are_stable():
+    """``fel_device_ms``, ``fel_mfu`` and ``me_roofline`` find the round
+    program and ME in the device trace by these names; a rename would turn
+    them to null without a word."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core.model_eval import model_evaluation
+    from repro.fl.hfl_runtime import BHFLConfig, BHFLRuntime
+    from repro.fl.hierarchy import build_hierarchy
+    from repro.models.mlp import MLPConfig
+
+    def module(lowered):
+        return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+    train, _ = api.make_mnist_like(n_train=64, n_test=8)
+    cfg = BHFLConfig(n_nodes=2, clients_per_node=2, fel_iterations=1,
+                     mlp=MLPConfig(hidden=8), engine="batched")
+    rt = BHFLRuntime(build_hierarchy(train, 2, 2, "iid"), cfg, None)
+    eng = rt._engine
+    idx, seeds = eng._prep(1)
+    lowered = eng._round_fn.lower(rt._global_flat, idx, seeds, eng._data,
+                                  eng._sizes_f, eng._bs_dev, eng._stepmask,
+                                  eng._template)
+    assert module(lowered) == "jit_round_fn"
+    W = jnp.ones((3, 16), jnp.float32)
+    assert module(model_evaluation.lower(W, jnp.ones(3))) == \
+        "jit_model_evaluation"
