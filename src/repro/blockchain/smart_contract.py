@@ -21,13 +21,13 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.core import crypto
 from repro.core.btsv import BTSVConfig, BTSVResult, btsv_round, init_history
 from repro.core.envelope import SignedEnvelope, verify_envelopes
-from repro.obs import spanned
+from repro.obs import device_nbytes, get_recorder, spanned
 
 
 def vote_payload_digest(node_id: int, round: int, vote: int,
@@ -70,6 +70,11 @@ class VoteTallyContract:
     submissions are batch-verified at tally time and forged ones dropped
     (and attributed in :attr:`rejected_votes`). ``require_signatures``
     additionally drops unsigned submissions.
+
+    A tally uploads its inputs in one transfer and pulls the whole
+    :class:`BTSVResult` back in one, so :meth:`tally` and :meth:`result`
+    return host NumPy arrays; only the rolling score history stays on the
+    device between tallies.
     """
 
     def __init__(self, n_nodes: int, cfg: BTSVConfig = BTSVConfig(),
@@ -166,17 +171,30 @@ class VoteTallyContract:
                 f"round {round}: {len(subs)}/{expected} submissions "
                 f"(of {self.n_nodes} nodes)")
         uniform = np.full((self.n_nodes,), 1.0 / self.n_nodes, np.float32)
-        votes = jnp.asarray([subs[i].vote if i in subs else -1
-                             for i in range(self.n_nodes)], jnp.int32)
-        P = jnp.stack([jnp.asarray(subs[i].predictions, jnp.float32)
-                       if i in subs else uniform       # masked placeholder
-                       for i in range(self.n_nodes)])
+        votes = np.asarray([subs[i].vote if i in subs else -1
+                            for i in range(self.n_nodes)], np.int32)
+        P = np.stack([np.asarray(subs[i].predictions, np.float32)
+                      if i in subs else uniform        # masked placeholder
+                      for i in range(self.n_nodes)])
         present = None
         if len(subs) < self.n_nodes:
-            present = jnp.asarray([1.0 if i in subs else 0.0
-                                   for i in range(self.n_nodes)], jnp.float32)
+            present = np.asarray([1.0 if i in subs else 0.0
+                                  for i in range(self.n_nodes)], np.float32)
+        # one upload of every input and one pull of the whole result: each
+        # transfer costs a round trip's latency, and every reader of the
+        # result (leader, block weights and advotes, re-election) is host code
+        rec = get_recorder()
+        with rec.span("device.put", on="btsv") as put:
+            if rec.enabled:
+                put.set(h2d_bytes=sum(
+                    a.nbytes for a in jax.tree.leaves((votes, P, present))))
+            votes, P, present = jax.device_put((votes, P, present))
         result, self._history = btsv_round(votes, P, self._history, self.cfg,
                                            present=present)
+        with rec.span("device.get", on="btsv") as pull:
+            if rec.enabled:
+                pull.set(d2h_bytes=device_nbytes(result))
+            result = jax.device_get(result)
         self._results[round] = result
         self._pending.pop(round, None)
         return result

@@ -33,6 +33,10 @@ class BTSVConfig(NamedTuple):
 
 
 class BTSVResult(NamedTuple):
+    """One tally's outputs. :func:`btsv_round` returns them as device
+    arrays; ``VoteTallyContract`` pulls them to the host in one copy, so
+    its results hold host NumPy arrays."""
+
     leader: jax.Array        # () int32 — e*(k)
     scores: jax.Array        # (N,) — score^i(k)
     weights: jax.Array       # (N,) — WV^i(k)

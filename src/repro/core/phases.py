@@ -591,8 +591,8 @@ class BlockMint(ConsensusPhase):
             model_digests=model_digests,
             global_model_digest=gw_digest,
             votes=votes,
-            vote_weights={i: float(ctx.btsv.weights[i]) for i in range(n)},
-            advotes={j: float(ctx.btsv.advotes[j]) for j in range(n)},
+            vote_weights=dict(enumerate(ctx.btsv.weights.tolist())),
+            advotes=dict(enumerate(ctx.btsv.advotes.tolist())),
             extra=extra,
         ).signed(self.nodes[leader].keypair)
         wal = self.wals.get(leader)

@@ -1,5 +1,7 @@
 """Block / ledger / smart-contract mechanics."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -8,6 +10,7 @@ from repro.blockchain.ledger import InvalidBlock, Ledger
 from repro.blockchain.smart_contract import (ContractError, VoteSubmission,
                                              VoteTallyContract)
 from repro.core import crypto
+from repro.core.btsv import btsv_round, init_history
 
 
 def _block(index=0, prev=GENESIS_HASH, leader=0):
@@ -191,3 +194,61 @@ def test_contract_tally_deterministic_and_cached():
     r1 = c.tally(0)
     r2 = c.tally(0)     # cached
     assert int(r1.leader) == 2 and r1 is r2
+
+
+def _tally_rounds(absent):
+    """Three rounds of one contract, each with its own votes and
+    predictions; voter ``absent`` (if any) never submits, and the tally
+    runs on the quorum of those that did. Returns the contract and each
+    round's (votes, P, present) as the contract builds them."""
+    n = 4
+    rng = np.random.default_rng(7)
+    c = VoteTallyContract(n)
+    inputs = []
+    for r in range(3):
+        votes = rng.integers(0, n, size=n).astype(np.int32)
+        P = rng.dirichlet(np.ones(n), size=n).astype(np.float32)
+        P /= P.sum(axis=1, keepdims=True)
+        present = np.ones(n, np.float32)
+        for i in range(n):
+            if i == absent:
+                votes[i], P[i], present[i] = -1, 1.0 / n, 0.0
+            else:
+                c.submit(VoteSubmission(i, r, int(votes[i]), P[i]))
+        if absent is None:
+            c.tally(r)
+        else:
+            c.tally(r, min_submissions=n - 1)
+        inputs.append((votes, P, None if absent is None else present))
+    return c, inputs
+
+
+@pytest.mark.parametrize("absent", [None, 3], ids=["strict", "quorum"])
+def test_contract_results_are_host_numpy(absent):
+    """The tally pulls its whole result to the host in one copy: every
+    field a consumer reads is a NumPy array, never a device array."""
+    c, _ = _tally_rounds(absent)
+    for r in range(3):
+        res = c.result(r)
+        for name, x in zip(res._fields, res):
+            assert isinstance(x, (np.ndarray, np.generic)), name
+            assert not isinstance(x, jax.Array), name
+
+
+@pytest.mark.parametrize("absent", [None, 3], ids=["strict", "quorum"])
+def test_contract_tally_matches_btsv_round_bit_for_bit(absent):
+    """The contract's host results are the jitted tally's outputs, bit for
+    bit: the same float32 inputs, each uploaded on its own here, with the
+    score history threaded round to round."""
+    c, inputs = _tally_rounds(absent)
+    history = init_history(c.n_nodes, c.cfg)
+    for r, (votes, P, present) in enumerate(inputs):
+        want, history = btsv_round(
+            jnp.asarray(votes), jnp.stack([jnp.asarray(p) for p in P]),
+            history, c.cfg,
+            present=None if present is None else jnp.asarray(present))
+        want = jax.device_get(want)
+        got = c.result(r)
+        for name, w, g in zip(want._fields, want, got):
+            assert g.dtype == w.dtype and g.shape == w.shape, name
+            assert g.tobytes() == w.tobytes(), (r, name)

@@ -424,22 +424,34 @@ def _ancestors(rec, span):
     return out
 
 
-def test_round_work_spans_nest_under_their_phases_and_count_bytes():
-    """One batched BHFL round under a TraceRecorder: each new span sits
-    under the phase or runtime span that contains the work, and the bytes
-    each copy moves are on its span and in the run's counters."""
-    import numpy as np
+@pytest.fixture(scope="module")
+def traced_round():
+    """One batched BHFL round (3 servers) under a TraceRecorder."""
     data = api.make_mnist_like(n_train=300, n_test=60)
     rec = obs.TraceRecorder("round")
     with obs.use_recorder(rec):
         run = api.run_bhfl(model="mlp", n_nodes=3, clients_per_node=2,
                            fel_iterations=1, rounds=1, engine="batched",
                            data=data)
+    return rec, run, data
+
+
+def _named(rec, name, **attrs):
+    return [s for s in rec.spans if s.name == name
+            and all(s.attrs.get(k) == v for k, v in attrs.items())]
+
+
+def test_round_work_spans_nest_under_their_phases_and_count_bytes(
+        traced_round):
+    """One batched BHFL round under a TraceRecorder: each new span sits
+    under the phase or runtime span that contains the work, and the bytes
+    each copy moves are on its span and in the run's counters."""
+    import numpy as np
+    rec, run, data = traced_round
     assert run.runtime.engine == "batched"
 
     def named(name, **attrs):
-        return [s for s in rec.spans if s.name == name
-                and all(s.attrs.get(k) == v for k, v in attrs.items())]
+        return _named(rec, name, **attrs)
 
     parent_of = {s.span_id: s.name for s in rec.spans}
     (wait_w,) = named("device.wait", on="W")
@@ -476,11 +488,39 @@ def test_round_work_spans_nest_under_their_phases_and_count_bytes():
     plan = 4 * eng.fel_iterations * eng.n_clusters * eng.n_clients_padded
     assert prep.attrs["h2d_bytes"] == plan * (
         eng.steps_per_iteration * eng.batch_pad + 1)
+    (btsv_put,) = named("device.put", on="btsv")
+    (btsv_get,) = named("device.get", on="btsv")
     counters = run.obs["counters"]
-    assert counters["transfer.d2h_bytes"] == (n + 1) * d * 4
+    assert counters["transfer.d2h_bytes"] == ((n + 1) * d * 4
+                                              + btsv_get.attrs["d2h_bytes"])
     assert counters["transfer.h2d_bytes"] == (test_bytes
-                                              + prep.attrs["h2d_bytes"])
+                                              + prep.attrs["h2d_bytes"]
+                                              + btsv_put.attrs["h2d_bytes"])
     assert counters["crypto.sha256_bytes"] >= 4 * n * d * 4
+
+
+def test_btsv_tally_crosses_to_the_device_once_each_way(traced_round):
+    """The tally uploads its votes and predictions in one transfer and
+    pulls its whole result in one, both inside ``btsv.tally``; the block
+    carries the pulled float32 weights and advotes exactly."""
+    import numpy as np
+    rec, run, _ = traced_round
+    n = 3
+    parent_of = {s.span_id: s.name for s in rec.spans}
+    (put,) = _named(rec, "device.put", on="btsv")
+    (get,) = _named(rec, "device.get", on="btsv")
+    assert parent_of[put.parent] == "btsv.tally"
+    assert parent_of[get.parent] == "btsv.tally"
+    assert put.attrs["h2d_bytes"] == n * 4 + n * n * 4   # votes, P
+    assert get.attrs["d2h_bytes"] == 4 + 4 * n * 4       # leader, 4 x (N,)
+    consensus = run.runtime.consensus
+    block = consensus.ledgers[0].blocks[-1]
+    res = consensus.contract.result(block.round)
+    assert block.vote_weights == {i: float(np.float32(w))
+                                  for i, w in enumerate(res.weights)}
+    assert block.advotes == {j: float(np.float32(a))
+                             for j, a in enumerate(res.advotes)}
+    assert block.leader_id == int(res.leader)
 
 
 # ---------------------------------------------------------------------------
