@@ -46,7 +46,7 @@ from repro.core.phases import (BlockMint, CommitReveal, ConsensusPhase,
 from repro.data.synthetic import make_mnist_like
 from repro.data.tokens import make_token_dataset
 from repro.fl.adapters import (LMAdapter, MLPAdapter, ModelAdapter,
-                               make_adapter, rwkv6_adapter,
+                               finch_adapter, make_adapter, rwkv6_adapter,
                                transformer_adapter)
 from repro.fl.batched_fel import BatchedFELEngine, BatchedTrainSpec
 from repro.fl.hfl_runtime import (AllNodesPlagiarizeError, BHFLConfig,
@@ -62,7 +62,7 @@ __all__ = [
     "LearningTask", "TaskAgreement", "RewardLedger", "negotiate_task",
     "BHFLConfig", "BHFLRuntime", "RoundMetrics", "build_hierarchy",
     "ModelAdapter", "MLPAdapter", "LMAdapter", "make_adapter",
-    "transformer_adapter", "rwkv6_adapter",
+    "transformer_adapter", "rwkv6_adapter", "finch_adapter",
     "PoFELConsensus", "ConsensusRecord", "BTSVConfig",
     "RoundContext", "ConsensusPhase", "CommitReveal", "ModelEvaluation",
     "VoteCollection", "Tally", "BlockMint", "run_phases",
@@ -80,6 +80,7 @@ class BHFLRun:
     agreement: TaskAgreement
     rewards: RewardLedger
     runtime: BHFLRuntime
+    # the runtime's history: only the newest record keeps gw(k)
     history: List[RoundMetrics] = field(default_factory=list)
     # set when the run was driven through a repro.sim scenario/fault env
     scenario_report: Optional[Any] = None
@@ -179,7 +180,8 @@ def run_bhfl(task: Optional[LearningTask] = None,
     Args:
         task: the on-chain task announcement; a default is synthesized
             (``target_loss`` and ``max_rounds`` drive termination).
-        model: 'mlp' | 'transformer' | 'rwkv6' or a ``ModelAdapter``.
+        model: 'mlp' | 'transformer' | 'rwkv6' | 'rwkv6-1.6b' (RWKV-6
+            "Finch" 1.6B at its published widths) or a ``ModelAdapter``.
             'mlp' trains with ``cfg``'s (paper §7.1) hyperparameters; the
             named LM families use their own LM-tuned defaults — pass an
             adapter instance (e.g. ``rwkv6_adapter(lr=...)``) to override.

@@ -276,6 +276,17 @@ class HCDSNode:
         """Model bytes of every node whose reveal passed all checks."""
         return {nid: rv.model_bytes for nid, rv in self._reveals.get(round, {}).items()}
 
+    def release_rounds_before(self, round: int) -> None:
+        """Drop the model bytes (its own and every accepted reveal) of the
+        rounds before ``round``, whose blocks are on this node's ledger:
+        no model of theirs is revealed or compared again. The newest
+        round's stay readable (:meth:`accepted_models`); commitments
+        stay."""
+        for r in [r for r in self._own if r < round]:
+            del self._own[r]
+        for r in [r for r in self._reveals if r < round]:
+            del self._reveals[r]
+
 
 def run_hcds_round(nodes: list[HCDSNode], models: list[Any], round: int,
                    public_keys: Optional[dict[int, crypto.Point]] = None,

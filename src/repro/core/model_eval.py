@@ -100,10 +100,17 @@ def model_evaluation(W: jax.Array, data_sizes: jax.Array,
     return MEResult(gw, sims, vote, preds)
 
 
+@jax.jit
+def stack_models(models: Sequence[Any]) -> jax.Array:
+    """(N, D) float32 W from N parameter pytrees, in one program: op by
+    op, each flatten and each row's reshape would hold a copy of W."""
+    return jnp.stack([flatten_model(m) for m in models])
+
+
 def model_evaluation_pytrees(models: Sequence[Any], data_sizes: Sequence[float],
                              g_max: float = 0.99) -> MEResult:
     """ME over a list of parameter pytrees (paper-faithful runtime path)."""
-    W = jnp.stack([flatten_model(m) for m in models])
+    W = stack_models(list(models))
     return model_evaluation(W, jnp.asarray(data_sizes, jnp.float32), g_max=g_max)
 
 

@@ -538,7 +538,19 @@ class BlockMint(ConsensusPhase):
             raise RuntimeError("BlockMint requires a prior Tally")
         if ctx.env is not None:
             self._run_networked(ctx)
-            return
+        else:
+            self._run_ideal(ctx)
+        # a model whose round's block is on a node's ledger is never
+        # revealed or compared again: that node's WAL drops the held
+        # commit payload, and the node the bytes of earlier rounds
+        for i, node in enumerate(self.nodes):
+            led = self.ledgers[i]
+            if led.blocks and led.blocks[-1].round == ctx.round:
+                node.release_rounds_before(ctx.round)
+                if i in self.wals:
+                    self.wals[i].release_model(ctx.round)
+
+    def _run_ideal(self, ctx: RoundContext) -> None:
         n = ctx.n_nodes
         leader = ctx.leader
         block = self._mint(ctx, leader, votes={i: int(ctx.votes[i])

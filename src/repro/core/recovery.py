@@ -15,7 +15,10 @@ durable layer the assumption needs:
   :class:`WALConflict` — re-signing a conflicting statement is
   structurally impossible, not merely discouraged. Logs can be
   memory-only (the simulator default) or backed by a JSONL file that
-  survives process restarts.
+  survives process restarts. A memory-only log holds a commit's model as
+  the ``bytes`` object it was given, until the round's block is on the
+  node's ledger (:meth:`NodeWAL.release_model`); a file-backed one
+  writes it into the record, hex-encoded.
 * :func:`wipe_volatile` / :func:`replay_wal` — the crash and the
   restart: clear an ``HCDSNode``'s in-memory round state, then rebuild
   this node's *own* commitments from its WAL so its re-broadcasts are
@@ -48,7 +51,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 from repro.blockchain.ledger import (InvalidBlock, Ledger, _block_from_dict,
                                      _block_to_dict)
 from repro.core import crypto
-from repro.obs import get_recorder, spanned
+from repro.obs import get_recorder
 
 
 class WALConflict(RuntimeError):
@@ -99,6 +102,9 @@ class NodeWAL:
         self.path = Path(path) if path is not None else None
         self._records: List[WALRecord] = []
         self._index: Dict[Tuple[str, int], WALRecord] = {}
+        # memory-only log: each commit's model bytes by round, the object
+        # the commit was given (no copy), until release_model(round)
+        self._models: Dict[int, bytes] = {}
         if self.path is not None and self.path.exists():
             for line in self.path.read_text().splitlines():
                 if line.strip():
@@ -142,18 +148,53 @@ class NodeWAL:
         return self._admit(WALRecord(kind, int(round), str(digest),
                                      dict(data)), write=True)
 
+    @property
+    def held_bytes(self) -> int:
+        """Model bytes the memory-only log holds for unminted rounds."""
+        return sum(len(b) for b in self._models.values())
+
+    def _log(self, kind: str, round: int, digest: str,
+             held: Optional[bytes] = None, **data: str) -> WALRecord:
+        """Append inside a ``wal.log`` span that reports the bytes held;
+        ``held`` is a commit's model kept beside its record."""
+        obs = get_recorder()
+        with obs.span("wal.log", cat="recovery", kind=kind) as span:
+            rec = self.append(kind, round, digest, **data)
+            if held is not None:
+                self._models.setdefault(rec.round, held)
+            if obs.enabled:
+                span.set(held_bytes=self.held_bytes)
+        return rec
+
     # -- typed helpers for the four protocol statements ----------------------
-    @spanned("wal.log", cat="recovery", kind="commit")
     def log_commit(self, round: int, model_bytes: bytes, nonce: bytes,
                    digest: bytes, tag: crypto.Signature) -> WALRecord:
         """Record a commit-sent: keyed by the *model* digest (two commits
         to the same model differ only in nonce and are not equivocation —
-        two commits to different models are)."""
-        return self.append(
-            "commit", round, crypto.sha256_digest(model_bytes).hex(),
-            nonce=nonce.hex(), commitment=digest.hex(),
-            model=model_bytes.hex(),
-            tag=crypto.Signature.coerce(tag).to_bytes().hex())
+        two commits to different models are). A file-backed log writes
+        the model into the record as hex; a memory-only one keeps the
+        ``bytes`` object itself beside the record."""
+        data = dict(nonce=nonce.hex(), commitment=digest.hex(),
+                    tag=crypto.Signature.coerce(tag).to_bytes().hex())
+        if self.path is not None:
+            data["model"] = model_bytes.hex()
+        return self._log("commit", round,
+                         crypto.sha256_digest(model_bytes).hex(),
+                         held=None if self.path is not None else model_bytes,
+                         **data)
+
+    def commit_model(self, rec: WALRecord) -> Optional[bytes]:
+        """The model bytes of a logged commit: from the record of a
+        file-backed log, else the held object; None once released."""
+        if "model" in rec.data:
+            return bytes.fromhex(rec.data["model"])
+        return self._models.get(rec.round)
+
+    def release_model(self, round: int) -> None:
+        """The round's block is on this node's ledger, so its model is
+        never revealed again: drop the held bytes. The commit record
+        stays, and still refuses a conflicting re-commit."""
+        self._models.pop(int(round), None)
 
     def commit_record(self, round: int,
                       model_bytes: bytes) -> Optional[WALRecord]:
@@ -171,26 +212,22 @@ class NodeWAL:
                 f"conflicting re-commit")
         return rec
 
-    @spanned("wal.log", cat="recovery", kind="reveal")
     def log_reveal(self, round: int, digest: bytes) -> WALRecord:
-        return self.append("reveal", round, digest.hex())
+        return self._log("reveal", round, digest.hex())
 
-    @spanned("wal.log", cat="recovery", kind="vote")
     def log_vote(self, round: int, vote: int) -> WALRecord:
-        return self.append("vote", round, str(int(vote)))
+        return self._log("vote", round, str(int(vote)))
 
-    @spanned("wal.log", cat="recovery", kind="block")
     def log_block(self, round: int, block_hash_hex: str) -> WALRecord:
-        return self.append("block", round, block_hash_hex)
+        return self._log("block", round, block_hash_hex)
 
-    @spanned("wal.log", cat="recovery", kind="checkpoint")
     def log_checkpoint(self, epoch: int, statement_digest_hex: str,
                        ) -> WALRecord:
         """Record a checkpoint countersignature (keyed by epoch): a member
         that crashed and rejoined mid-epoch replays its WAL, and signing a
         *conflicting* checkpoint statement for the same epoch raises
         :class:`WALConflict` instead of equivocating across shards."""
-        return self.append("checkpoint", epoch, statement_digest_hex)
+        return self._log("checkpoint", epoch, statement_digest_hex)
 
 
 # ---------------------------------------------------------------------------
@@ -209,8 +246,10 @@ def wipe_volatile(node: Any) -> None:
 def replay_wal(node: Any, wal: NodeWAL) -> int:
     """The restart: rebuild ``node``'s own commitments from its WAL so a
     re-broadcast is byte-identical to the pre-crash statement. Idempotent —
-    replaying an already-replayed log changes nothing. Returns the number
-    of records applied."""
+    replaying an already-replayed log changes nothing. Commits of rounds
+    whose block is already on the node's ledger (model released) are
+    never revealed again and are skipped. Returns the number of records
+    applied."""
     applied = 0
     for rec in wal.records():
         if rec.kind != "commit":
@@ -218,10 +257,13 @@ def replay_wal(node: Any, wal: NodeWAL) -> int:
             # re-signing (checked at signing time); they carry no volatile
             # state to rebuild
             continue
+        model = wal.commit_model(rec)
+        if model is None:
+            continue
         node.restore_own_commit(
             rec.round,
             nonce=bytes.fromhex(rec.data["nonce"]),
-            model_bytes=bytes.fromhex(rec.data["model"]),
+            model_bytes=model,
             digest=bytes.fromhex(rec.data["commitment"]),
             tag=crypto.Signature.coerce(rec.data["tag"]))
         applied += 1

@@ -1,5 +1,6 @@
 from repro.fl.adapters import (EvalResult, LMAdapter, MLPAdapter, ModelAdapter,
-                               make_adapter, rwkv6_adapter, transformer_adapter)
+                               finch_adapter, make_adapter, rwkv6_adapter,
+                               transformer_adapter)
 from repro.fl.batched_fel import (BatchedFELEngine, BatchedTrainSpec,
                                   engine_for)
 from repro.fl.client import Client, local_train
@@ -13,4 +14,5 @@ __all__ = ["Client", "local_train", "fedavg", "FELCluster", "build_hierarchy",
            "AllNodesPlagiarizeError",
            "BatchedFELEngine", "BatchedTrainSpec", "engine_for",
            "ModelAdapter", "MLPAdapter", "LMAdapter", "EvalResult",
-           "make_adapter", "transformer_adapter", "rwkv6_adapter"]
+           "make_adapter", "transformer_adapter", "rwkv6_adapter",
+           "finch_adapter"]

@@ -12,7 +12,8 @@ Adapters:
 * :class:`MLPAdapter`   — the paper-faithful MNIST MLP (§7.1).
 * :class:`LMAdapter`    — any ``repro.models.model_api.Model`` family over
   token data; :func:`transformer_adapter` and :func:`rwkv6_adapter` build
-  reduced-scale instances that run on CPU.
+  reduced-scale instances that run on CPU, :func:`finch_adapter` RWKV-6
+  "Finch" 1.6B at its published widths (``"rwkv6-1.6b"``).
 
 Flatten/unflatten share the canonical sorted-keypath roundtrip in
 ``repro.core.serialization``, so model bytes, ME vectors, and checkpoint
@@ -21,6 +22,7 @@ digests always agree.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, NamedTuple, Protocol, runtime_checkable
@@ -254,15 +256,28 @@ class LMAdapter(_SerializationFlatten):
         return self._batched_spec
 
     def evaluate(self, params: Any, dataset: Any) -> EvalResult:
-        from repro.models.model_api import DEFAULT_AUX_WEIGHT, _token_ce_loss
-        rows = jnp.asarray(dataset.tokens)
-        batch = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
-        # one forward pass serves both metrics (Model.loss would rerun it)
-        logits, aux = self.model.forward(params, batch)
-        acc = jnp.mean((jnp.argmax(logits, axis=-1)
-                        == batch["labels"]).astype(jnp.float32))
-        loss = _token_ce_loss(logits, batch["labels"]) + DEFAULT_AUX_WEIGHT * aux
+        """Next-token top-1 accuracy and CE loss on ``dataset``'s rows, one
+        jitted call per test-set shape."""
+        rec = get_recorder()
+        with rec.span("device.put", on="test_set") as put:
+            rows = jnp.asarray(dataset.tokens)
+            if rec.enabled and not isinstance(dataset.tokens, jax.Array):
+                put.set(h2d_bytes=dataset.tokens.nbytes)
+        acc, loss = _lm_evaluate(self.model, params, rows)
+        device_wait("eval", (acc, loss))
         return EvalResult(float(acc), float(loss))
+
+
+@partial(jax.jit, static_argnames=("model",))
+def _lm_evaluate(model: Model, params: Any, rows: jax.Array):
+    from repro.models.model_api import DEFAULT_AUX_WEIGHT, _token_ce_loss
+    batch = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
+    # one forward pass serves both metrics (Model.loss would rerun it)
+    logits, aux = model.forward(params, batch)
+    acc = jnp.mean((jnp.argmax(logits, axis=-1)
+                    == batch["labels"]).astype(jnp.float32))
+    loss = _token_ce_loss(logits, batch["labels"]) + DEFAULT_AUX_WEIGHT * aux
+    return acc, loss
 
 
 def tiny_transformer_config(vocab_size: int = 256, d_model: int = 64,
@@ -282,7 +297,8 @@ def tiny_rwkv6_config(vocab_size: int = 256, d_model: int = 64,
         name="bhfl-rwkv6-tiny", family="ssm",
         n_layers=n_layers, d_model=d_model, n_heads=d_model // 32,
         n_kv_heads=d_model // 32, d_ff=2 * d_model, vocab_size=vocab_size,
-        rwkv=True, rwkv_head_size=32, source="repro.fl.adapters")
+        rwkv=True, rwkv_head_size=32, rwkv_mix_lora=8, rwkv_decay_lora=8,
+        source="repro.fl.adapters")
 
 
 def transformer_adapter(vocab_size: int = 256, d_model: int = 64,
@@ -296,13 +312,28 @@ def rwkv6_adapter(vocab_size: int = 256, d_model: int = 64,
     return LMAdapter(tiny_rwkv6_config(vocab_size, d_model, n_layers), **hp)
 
 
+_HYPERPARAMETERS = ("local_epochs", "batch_size", "lr", "momentum", "decay")
+
+
+def finch_adapter(**kwargs) -> LMAdapter:
+    """RWKV-6 "Finch" 1.6B (``configs/rwkv6_1_6b``) at its published
+    widths. ``LMAdapter`` hyperparameters pass through; any other keyword
+    replaces that ``ArchConfig`` field, e.g. ``n_layers`` or
+    ``vocab_size`` for a cut in depth or vocabulary."""
+    from repro.configs import get_config
+    hp = {k: kwargs.pop(k) for k in _HYPERPARAMETERS if k in kwargs}
+    return LMAdapter(dataclasses.replace(get_config("rwkv6-1.6b"), **kwargs),
+                     **hp)
+
+
 _NAMED = {"mlp": MLPAdapter, "transformer": transformer_adapter,
-          "rwkv6": rwkv6_adapter}
+          "rwkv6": rwkv6_adapter, "rwkv6-1.6b": finch_adapter}
 
 
 def make_adapter(model: "str | ModelAdapter", **kwargs) -> ModelAdapter:
     """Resolve ``model`` to an adapter: pass through an adapter instance,
-    or build one by name ('mlp' | 'transformer' | 'rwkv6')."""
+    or build one by name ('mlp' | 'transformer' | 'rwkv6' |
+    'rwkv6-1.6b')."""
     if isinstance(model, str):
         try:
             return _NAMED[model](**kwargs)

@@ -16,6 +16,20 @@ the whole FEL phase of a round into ONE device program:
 so one call produces the stacked flat ``(N, D)`` model matrix W(k) that
 Model Evaluation consumes directly — no per-model flatten, no host hops.
 
+Layout. The vmapped program above holds every client's training state at
+once: N·C copies of the float32 parameters, momentum and gradients, and
+the saved activations. The engine counts, from the shapes, the bytes of
+one client in flight (:meth:`BatchedFELEngine._client_bytes`: its
+parameters, momentum, gradients and gathered batch; the saved
+activations are not counted) and compares N·C of them, beside the fixed
+buffers, with the device's ``bytes_limit``. Where they do not fit, the
+same round runs ``sequential``: clusters under ``lax.map``, clients under a
+``lax.scan`` that adds each client's masked Eq. 1 share into one running
+float32 sum, so one client's state lives at a time. Same seeds, batch
+plan and masks; the FedAvg sum is reduced in client order (the vmapped
+einsum reduces in the backend's order). A backend that reports no limit
+(the CPU) keeps the vmapped program.
+
 Numerical contract: with the same seeds the engine reproduces the
 reference loop step for step — identical batch permutations (the same
 numpy RNG stream, precomputed host-side into an index tensor), identical
@@ -75,6 +89,19 @@ _ROUND_FN_CACHE_MAX = 32
 _TRACE_COUNT = [0]
 
 
+def device_bytes_limit() -> Optional[int]:
+    """Bytes the default device can hold, where its backend reports them
+    (``memory_stats()["bytes_limit"]``); None elsewhere."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) if limit else None
+
+
+def _nbytes(tree: Any) -> int:
+    return sum(int(np.prod(a.shape, dtype=np.int64)) * np.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(tree))
+
+
 def compile_count() -> int:
     """How many times a batched round program has been traced (≈ compiled)
     in this process — the observable for shape-bucket cache-hit tests."""
@@ -123,7 +150,10 @@ class BatchedFELEngine:
         if self.n_clusters == 0 or self.n_clients == 0:
             raise ValueError("batched engine needs at least one cluster "
                              "with at least one client")
-        self._template = template_params
+        # the shapes and dtypes the round program unflattens gw(k-1) into;
+        # the engine holds no parameter values of its own
+        self._template = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), template_params)
 
         def _dim(x: int) -> int:
             """Bucketed axis extent: next pow2 under bucketing, exact else."""
@@ -204,25 +234,65 @@ class BatchedFELEngine:
         self._sizes_f = jnp.asarray(sizes, jnp.float32)
         self._bs_dev = jnp.asarray(self._bs)
 
+        self.layout, self.clients_in_flight = self._choose_layout()
         self._round_fn = self._cached_round_fn()
+
+    # -- layout: every client in flight, or one --------------------------------
+    def _unrolls(self) -> tuple:
+        """(unroll of the step scan, unroll of the FEL-iteration scan).
+        The sequential layout runs where memory is short, and a step loop
+        keeps two copies of its parameter and momentum carry: there a few
+        steps are unrolled."""
+        T, I = self.steps_per_iteration, self.fel_iterations
+        few = T == 1 or (self.layout == "sequential" and T <= 8)
+        return (True if few else 1,
+                True if (T == 1 and I <= 8) else 1)
+
+    def _choose_layout(self) -> Tuple[str, int]:
+        """(layout, clients in flight): ``vmap`` with all N·C when their
+        training state fits the device beside the round's fixed buffers
+        (W(k), one parameter carry per cluster, the global model in and
+        its float32 copy, the stacked data), else ``sequential`` with one.
+        """
+        slots = self.n_clusters * self.n_clients_padded
+        limit = device_bytes_limit()
+        if limit is None:
+            return "vmap", slots
+        p32 = 4 * sum(int(np.prod(a.shape, dtype=np.int64))
+                      for a in jax.tree.leaves(self._template))
+        fixed = (2 * self.n_clusters + 2) * p32 + _nbytes(self._data)
+        if fixed + slots * self._client_bytes(p32) > limit:
+            return "sequential", 1
+        return "vmap", slots
+
+    def _client_bytes(self, p32: int) -> int:
+        """Device bytes of one client's local training, from the shapes:
+        float32 parameters, momentum and gradients, and the gathered
+        batch. The saved activations are left out: the state alone
+        decides for the models run so far (the paper's MLP fits many
+        times over, RWKV-6 1.6B does not fit once)."""
+        batch = _nbytes(self._data) * self.batch_pad // max(
+            1, self.n_clusters * self.n_clients_padded * self.n_max)
+        return 3 * p32 + batch
 
     # -- the single-device-program round ------------------------------------
     def _cached_round_fn(self):
         """The jitted round program for this engine's static configuration,
         shared across engine instances through the module-level cache.
 
-        Everything shape- or value-dependent (the stacked data, sizes,
-        masks, the parameter template) is a traced *argument*, so the only
-        cache-key material is the training spec and the unroll flags —
-        rebuilt runtimes whose bucketed shapes match re-enter jax.jit's own
-        cache and skip compilation entirely.
+        Everything value-dependent (the stacked data, sizes, masks) is a
+        traced *argument*, so the only cache-key material is the training
+        spec, the layout, the unroll flags and the parameters' shapes and
+        dtypes — rebuilt runtimes whose bucketed shapes match re-enter
+        jax.jit's own cache and skip compilation entirely.
         """
         spec = self.spec
-        T, I = self.steps_per_iteration, self.fel_iterations
-        unroll_steps = True if T == 1 else 1
-        unroll_iters = True if (T == 1 and I <= 8) else 1
+        unroll_steps, unroll_iters = self._unrolls()
+        leaves, treedef = jax.tree_util.tree_flatten(self._template)
         key = (spec.per_example_loss, spec.lr, spec.momentum, spec.decay,
-               self._uniform, self.batch_pad, unroll_steps, unroll_iters)
+               self._uniform, self.batch_pad, unroll_steps, unroll_iters,
+               self.layout, treedef,
+               tuple((a.shape, str(a.dtype)) for a in leaves))
         fn = _ROUND_FN_CACHE.get(key)
         rec = get_recorder()
         if rec.enabled:
@@ -230,12 +300,12 @@ class BatchedFELEngine:
                         else "fel.round_fn_cache_misses")
         if fn is None:
             fn = jax.jit(_build_round_fn(spec, self._uniform, self.batch_pad,
-                                         unroll_steps, unroll_iters))
+                                         unroll_steps, unroll_iters,
+                                         self.layout, self._template))
             _ROUND_FN_CACHE[key] = fn
             if len(_ROUND_FN_CACHE) > _ROUND_FN_CACHE_MAX:
                 _ROUND_FN_CACHE.popitem(last=False)
         return fn
-
 
     # -- host-side per-round prep (cheap: numpy permutations only) -----------
     def _batch_plan(self, round_seed: int) -> tuple[np.ndarray, np.ndarray]:
@@ -282,7 +352,7 @@ class BatchedFELEngine:
             idx, seeds = self._prep(round_seed)
             return self._round_fn(jnp.asarray(global_flat), idx, seeds,
                                   self._data, self._sizes_f, self._bs_dev,
-                                  self._stepmask, self._template)
+                                  self._stepmask)
         # the host's share: the batch plan and its upload
         with rec.span("fel.prep", cat="fel") as prep:
             idx, seeds = self._prep(round_seed)
@@ -293,32 +363,29 @@ class BatchedFELEngine:
         # dispatches that traced a fresh program (the jit-compile half of
         # the compile-vs-execute split)
         traces_before = _TRACE_COUNT[0]
-        rec.open_span("fel.dispatch", cat="fel")
+        rec.open_span("fel.dispatch", cat="fel", layout=self.layout,
+                      clients_in_flight=self.clients_in_flight)
         W = self._round_fn(jnp.asarray(global_flat), idx, seeds,
                            self._data, self._sizes_f, self._bs_dev,
-                           self._stepmask, self._template)
+                           self._stepmask)
         rec.close_span(compiled=_TRACE_COUNT[0] > traces_before)
         rec.counter("fel.dispatches")
+        if self.layout == "sequential":
+            rec.counter("fel.sequential_dispatches")
         return W
 
 
-def _build_round_fn(spec: BatchedTrainSpec, uniform: bool, B: int,
-                    unroll_steps, unroll_iters):
-    """The (unjitted) round program for one static configuration.
-
-    Everything instance-specific — the stacked client data, sizes, batch
-    widths, step masks, and the parameter template — arrives as traced
-    arguments, so one jitted wrapper serves every engine whose bucketed
-    shapes match (see :class:`BatchedFELEngine._cached_round_fn`).
-    """
+def _make_train_client(spec: BatchedTrainSpec, uniform: bool, B: int,
+                       unroll_steps):
+    """One client's local SGD, ``(params, data_c, bs_c, idx_c, smask_c,
+    seed) -> params``: a ``lax.scan`` over its epochs × batches. Padding
+    steps (smask False) advance neither params, momentum, the decay step
+    counter, nor the PRNG key — exactly the reference loop. When every
+    shard is uniform (no padding steps, full batch width — checked
+    statically at engine build) the masking selects disappear from the
+    compiled program entirely."""
 
     def train_client(params, data_c, bs_c, idx_c, smask_c, seed):
-        """lax.scan over this client's epochs × batches. Padding steps
-        (smask False) advance neither params, momentum, the decay step
-        counter, nor the PRNG key — exactly the reference loop. When
-        every shard is uniform (no padding steps, full batch width —
-        checked statically at engine build) the masking selects
-        disappear from the compiled program entirely."""
         key0 = jax.random.key(seed)
         mom0 = jax.tree.map(jnp.zeros_like, params)
 
@@ -362,27 +429,65 @@ def _build_round_fn(spec: BatchedTrainSpec, uniform: bool, B: int,
                                         unroll=unroll_steps)
         return pf
 
+    return train_client
+
+
+def _build_round_fn(spec: BatchedTrainSpec, uniform: bool, B: int,
+                    unroll_steps, unroll_iters, layout: str, template: Any):
+    """The (unjitted) round program for one static configuration.
+
+    Everything instance-specific — the stacked client data, sizes, batch
+    widths and step masks — arrives as traced arguments, so one jitted
+    wrapper serves every engine whose bucketed shapes match (see
+    :class:`BatchedFELEngine._cached_round_fn`). ``template`` gives the
+    parameters' shapes and dtypes (``ShapeDtypeStruct`` leaves).
+    ``layout`` is ``vmap`` (every client in flight) or ``sequential``
+    (clusters under ``lax.map``, clients under ``lax.scan``).
+    """
+    train_client = _make_train_client(spec, uniform, B, unroll_steps)
+
+    def vmapped_clients(params, data_n, sizes_n, bs_n, idx_i, smask_n,
+                        seeds_i):
+        locals_ = jax.vmap(train_client, in_axes=(None, 0, 0, 0, 0, 0))(
+            params, data_n, bs_n, idx_i, smask_n, seeds_i)
+        # Eq. 1 at the edge: data-size weights; empty/padded
+        # clients carry exact zero weight so they drop out of the
+        # reduction bit-for-bit
+        lam = sizes_n / jnp.maximum(jnp.sum(sizes_n), 1.0)
+        return jax.tree.map(
+            lambda l: jnp.einsum("c,c...->...", lam,
+                                 l.astype(jnp.float32)).astype(l.dtype),
+            locals_)
+
+    def sequential_clients(params, data_n, sizes_n, bs_n, idx_i, smask_n,
+                           seeds_i):
+        lam = sizes_n / jnp.maximum(jnp.sum(sizes_n), 1.0)
+
+        def add_client(acc, xs):
+            data_c, bs_c, idx_c, smask_c, seed, lam_c = xs
+            local = train_client(params, data_c, bs_c, idx_c, smask_c, seed)
+            return jax.tree.map(lambda a, l: a + lam_c * l.astype(jnp.float32),
+                                acc, local), None
+
+        acc0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        acc, _ = jax.lax.scan(add_client, acc0, (data_n, bs_n, idx_i,
+                                                 smask_n, seeds_i, lam))
+        return jax.tree.map(lambda a, p: a.astype(p.dtype), acc, params)
+
+    fedavg_clients = (sequential_clients if layout == "sequential"
+                      else vmapped_clients)
+
     def train_cluster(params0, data_n, sizes_n, bs_n, idx_n, smask_n,
                       seeds_n):
-        """fel_iterations × (vmap clients → masked FedAvg), in-graph."""
+        """fel_iterations × (clients → masked FedAvg), in-graph."""
 
         def fel_iter(params, xs):
             idx_i, seeds_i = xs
-            locals_ = jax.vmap(train_client,
-                               in_axes=(None, 0, 0, 0, 0, 0))(
-                params, data_n, bs_n, idx_i, smask_n, seeds_i)
-            # Eq. 1 at the edge: data-size weights; empty/padded
-            # clients carry exact zero weight so they drop out of the
-            # reduction bit-for-bit
-            tot = jnp.sum(sizes_n)
-            lam = sizes_n / jnp.maximum(tot, 1.0)
-            avg = jax.tree.map(
-                lambda l: jnp.einsum(
-                    "c,c...->...", lam,
-                    l.astype(jnp.float32)).astype(l.dtype),
-                locals_)
+            avg = fedavg_clients(params, data_n, sizes_n, bs_n, idx_i,
+                                 smask_n, seeds_i)
             # a dataless cluster keeps the incoming global model; its
             # consensus weight (|DS_m| = 0) already zeroes it in Eq. 1
+            tot = jnp.sum(sizes_n)
             params = jax.tree.map(lambda a, p: jnp.where(tot > 0, a, p),
                                   avg, params)
             return params, None
@@ -391,22 +496,30 @@ def _build_round_fn(spec: BatchedTrainSpec, uniform: bool, B: int,
                                 unroll=unroll_iters)
         return flatten_pytree(final)
 
-    def round_fn(global_flat, idx, seeds, data, sizes_f, bs_dev, stepmask,
-                 template):
+    def round_fn(global_flat, idx, seeds, data, sizes_f, bs_dev, stepmask):
         _TRACE_COUNT[0] += 1    # runs at trace time only: ≈ compile count
         # train in float32: the reference loop's SGD update promotes
         # low-precision (bf16) params to f32 after the first step
         # anyway, and a lax.scan carry needs one stable dtype
-        params0 = jax.tree.map(lambda l: l.astype(jnp.float32),
-                               unflatten_pytree_device(global_flat,
-                                                       template))
-        # (I, N, ...) -> (N, I, ...): the cluster vmap is outermost,
+        def start(global_flat):
+            return jax.tree.map(lambda l: l.astype(jnp.float32),
+                                unflatten_pytree_device(global_flat,
+                                                        template))
+
+        # (I, N, ...) -> (N, I, ...): the cluster map is outermost,
         # the fel_iterations scan runs inside it
         idx_n = jnp.swapaxes(idx, 0, 1)
         seeds_n = jnp.swapaxes(seeds, 0, 1)
+        per_cluster = (data, sizes_f, bs_dev, idx_n, stepmask, seeds_n)
+        if layout == "sequential":
+            # each cluster unflattens gw(k-1) itself, so no float32 copy
+            # of the global model lives beside the running cluster's
+            return jax.lax.map(
+                lambda xs: train_cluster(start(global_flat), *xs),
+                per_cluster)
         return jax.vmap(train_cluster,
                         in_axes=(None, 0, 0, 0, 0, 0, 0))(
-            params0, data, sizes_f, bs_dev, idx_n, stepmask, seeds_n)
+            start(global_flat), *per_cluster)
 
     return round_fn
 

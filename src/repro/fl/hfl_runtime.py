@@ -99,6 +99,11 @@ class BHFLRuntime:
 
     ``adapter`` chooses the model family (default: the paper's MNIST MLP);
     the clusters' client datasets must match the adapter's batch format.
+
+    ``history`` holds one :class:`RoundMetrics` per round. Only the newest
+    round's record keeps gw(k) (``consensus.global_model``); older records
+    drop it when the next round is recorded, and their blocks keep its
+    digest. So a runtime holds one global model, not one a round.
     """
 
     def __init__(self, clusters: List[FELCluster], cfg: BHFLConfig,
@@ -330,12 +335,21 @@ class BHFLRuntime:
 
         metrics = RoundMetrics(k, record.leader_id, acc, loss,
                                float(np.mean(record.similarities)), record)
-        self.history.append(metrics)
+        self._record(metrics)
         if env is not None:
             with rec.span("end_round", round=k, sim_env=env):
                 env.end_round(k, metrics, aborted=False)
         rec.close_span(aborted=False)
         return metrics
+
+    def _record(self, metrics: RoundMetrics) -> None:
+        """Appends a minted round to ``history``; the record before it
+        drops its gw(k) (see the class docstring)."""
+        for old in reversed(self.history):
+            if old.consensus is not None:
+                old.consensus.global_model = None
+                break
+        self.history.append(metrics)
 
     def run(self, n_rounds: int) -> List[RoundMetrics]:
         return [self.run_round() for _ in range(n_rounds)]

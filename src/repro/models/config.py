@@ -35,6 +35,8 @@ class ArchConfig:
     attn_every: int = 0         # hybrid: shared attention block period
     rwkv: bool = False
     rwkv_head_size: int = 64
+    rwkv_mix_lora: int = 32     # Finch D_MIX_LORA (token-shift ddlerp)
+    rwkv_decay_lora: int = 64   # Finch D_DECAY_LORA
     # serving
     sliding_window: int = 0     # 0 = full attention
     source: str = ""

@@ -1,6 +1,7 @@
 """Full-model definitions for the attention-free / hybrid families:
 
-* rwkv6 — stack of RWKV-6 blocks (config.rwkv=True), O(1)-state decode.
+* rwkv6 — RWKV-6 "Finch" (config.rwkv=True): embedding, ``ln0``, Finch
+  blocks, ``ln_out``, head; O(1)-state decode.
 * zamba2 hybrid — Mamba2 blocks with a single SHARED attention+MLP block
   applied every ``attn_every`` layers (Zamba2's parameter-sharing trick):
   81 layers = 13 groups × (5 mamba + shared attn) + 3 trailing mamba.
@@ -19,8 +20,9 @@ from repro.models.layers import (COMPUTE_DTYPE, apply_rope, blockwise_attention,
                                  rms_norm, swiglu_mlp)
 from repro.models.mamba2 import (Mamba2Config, Mamba2State, mamba2_apply,
                                  mamba2_init, mamba2_init_state)
-from repro.models.rwkv6 import (RWKVBlockState, RWKVConfig, rwkv_block_apply,
-                                rwkv_block_init, rwkv_init_state)
+from repro.models.rwkv6 import (RWKVBlockState, RWKVConfig, layer_norm,
+                                rwkv_block_apply, rwkv_block_init,
+                                rwkv_init_state)
 
 PARAM_DTYPE = jnp.bfloat16
 
@@ -34,24 +36,37 @@ def _stack(trees: list) -> Any:
 # ---------------------------------------------------------------------------
 
 def rwkv_cfg_of(cfg: ArchConfig) -> RWKVConfig:
-    return RWKVConfig(cfg.d_model, head_size=cfg.rwkv_head_size, d_ff=cfg.d_ff)
+    return RWKVConfig(cfg.d_model, head_size=cfg.rwkv_head_size, d_ff=cfg.d_ff,
+                      mix_lora=cfg.rwkv_mix_lora,
+                      decay_lora=cfg.rwkv_decay_lora)
 
 
 def rwkv_init_params(cfg: ArchConfig, key: jax.Array) -> dict:
+    """Embedding and head stored in bf16 (``PARAM_DTYPE``), every other
+    leaf in float32."""
     rcfg = rwkv_cfg_of(cfg)
     ks = jax.random.split(key, cfg.n_layers + 2)
+    D = cfg.d_model
     return {
-        "embed": embed_init(ks[0], cfg.vocab_size, cfg.d_model, PARAM_DTYPE),
-        "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
-        "lm_head": dense_init(ks[1], cfg.d_model, cfg.vocab_size, PARAM_DTYPE),
-        "layers": _stack([rwkv_block_init(rcfg, k) for k in ks[2:]]),
+        "embed": embed_init(ks[0], cfg.vocab_size, D, PARAM_DTYPE),
+        "ln0_w": jnp.ones((D,), jnp.float32),
+        "ln0_b": jnp.zeros((D,), jnp.float32),
+        "ln_out_w": jnp.ones((D,), jnp.float32),
+        "ln_out_b": jnp.zeros((D,), jnp.float32),
+        "lm_head": dense_init(ks[1], D, cfg.vocab_size, PARAM_DTYPE),
+        "layers": _stack([rwkv_block_init(rcfg, k, i, cfg.n_layers)
+                          for i, k in enumerate(ks[2:])]),
     }
 
 
 def rwkv_forward(params: dict, tokens: jax.Array, cfg: ArchConfig,
                  remat: bool = True) -> jax.Array:
+    """Finch: embedding → ``ln0`` → blocks → ``ln_out`` → head, activations
+    in ``COMPUTE_DTYPE``; each block is rematerialised in the backward
+    pass when ``remat``."""
     rcfg = rwkv_cfg_of(cfg)
     x = params["embed"].astype(COMPUTE_DTYPE)[tokens]
+    x = layer_norm(x, params["ln0_w"], params["ln0_b"])
 
     def body(x, layer):
         fn = rwkv_block_apply
@@ -61,7 +76,7 @@ def rwkv_forward(params: dict, tokens: jax.Array, cfg: ArchConfig,
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
+    x = layer_norm(x, params["ln_out_w"], params["ln_out_b"])
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(x.dtype))
 
 
@@ -79,6 +94,7 @@ def rwkv_decode_step(params: dict, cache: RWKVBlockState, tokens: jax.Array,
     del pos
     rcfg = rwkv_cfg_of(cfg)
     x = params["embed"].astype(COMPUTE_DTYPE)[tokens]
+    x = layer_norm(x, params["ln0_w"], params["ln0_b"])
 
     def body(x, scanned):
         layer, st = scanned
@@ -86,7 +102,7 @@ def rwkv_decode_step(params: dict, cache: RWKVBlockState, tokens: jax.Array,
         return x, st
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-    x = rms_norm(x, params["final_norm"])
+    x = layer_norm(x, params["ln_out_w"], params["ln_out_b"])
     logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(x.dtype))
     return logits, new_cache
 

@@ -275,3 +275,110 @@ def test_api_engine_kwarg():
                        fel_iterations=1, rounds=2, engine="batched")
     assert run.runtime.engine == "batched"
     assert run.chain_valid and run.chain_height == 2
+
+
+# ---------------------------------------------------------------------------
+# layout: every client in flight (vmap) or one at a time (sequential)
+# ---------------------------------------------------------------------------
+
+def _ragged_hierarchy():
+    train, test = make_mnist_like(n_train=600, n_test=40)
+    rng = np.random.default_rng(5)
+    order = rng.permutation(len(train))
+    cuts = [[70, 130, 0], [96, 64, 50]]     # ragged, one empty shard
+    clusters, off = [], 0
+    for n, sizes in enumerate(cuts):
+        clients = []
+        for j, s in enumerate(sizes):
+            clients.append(Client(n * 3 + j, train.subset(order[off:off + s])))
+            off += s
+        clusters.append(FELCluster(n, clients))
+    return clusters, test
+
+
+@pytest.mark.parametrize("hierarchy", ["uniform", "ragged"])
+def test_sequential_layout_reproduces_vmapped(monkeypatch, hierarchy):
+    """With a device budget too small for every client's training state,
+    the engine runs clusters under lax.map and clients under lax.scan;
+    W(k) and gw(k) match the vmapped program up to FedAvg's reduction
+    order, for the same seeds, batch plan and masks."""
+    from repro.fl import batched_fel
+    if hierarchy == "uniform":
+        train, test = make_mnist_like(n_train=720, n_test=60)
+        clusters = lambda: build_hierarchy(train, 3, 2, "iid")
+    else:
+        clusters = lambda: _ragged_hierarchy()[0]
+        test = _ragged_hierarchy()[1]
+    n = len(clusters())
+    cfg = BHFLConfig(n_nodes=n, clients_per_node=2, fel_iterations=2,
+                     engine="batched")
+    vm = BHFLRuntime(clusters(), cfg, test)
+    monkeypatch.setattr(batched_fel, "device_bytes_limit", lambda: 1 << 20)
+    sq = BHFLRuntime(clusters(), cfg, test)
+    assert (vm._engine.layout, sq._engine.layout) == ("vmap", "sequential")
+    assert sq._engine.clients_in_flight == 1
+    assert vm._engine.clients_in_flight == vm._engine.n_clusters * \
+        vm._engine.n_clients_padded
+    from repro import obs
+    rec = obs.TraceRecorder("t")
+    for _ in range(2):
+        W_v = np.asarray(vm._engine.run_round(vm._global_flat, 7))
+        with obs.use_recorder(rec):
+            W_s = np.asarray(sq._engine.run_round(sq._global_flat, 7))
+        np.testing.assert_allclose(W_s, W_v, rtol=2e-6, atol=2e-7)
+        m_v, m_s = vm.run_round(), sq.run_round()
+        assert m_v.leader_id == m_s.leader_id
+        np.testing.assert_allclose(_global_flat(sq), _global_flat(vm),
+                                   rtol=2e-6, atol=2e-7)
+    assert rec.metrics_snapshot()["counters"]["fel.sequential_dispatches"] == 2
+    assert {(s.attrs["layout"], s.attrs["clients_in_flight"])
+            for s in rec.spans if s.name == "fel.dispatch"} == {
+        ("sequential", 1)}
+
+
+def test_paper_mlp_keeps_the_vmapped_program(monkeypatch):
+    """The paper's MLP fits a 16 GB chip many times over: the engine keeps
+    the vmapped program, and says so on its dispatch span."""
+    from repro import obs
+    from repro.fl import batched_fel
+    train, test = make_mnist_like(n_train=8 * 5 * 40, n_test=20)
+    cfg = BHFLConfig(n_nodes=8, clients_per_node=5, fel_iterations=1,
+                     engine="batched")
+    assert BHFLRuntime(build_hierarchy(train, 8, 5, "iid"), cfg,
+                       test)._engine.layout == "vmap"    # no limit reported
+    monkeypatch.setattr(batched_fel, "device_bytes_limit",
+                        lambda: 16 * 10**9)
+    rt = BHFLRuntime(build_hierarchy(train, 8, 5, "iid"), cfg, test)
+    rec = obs.TraceRecorder("t")
+    with obs.use_recorder(rec):
+        rt.run_round()
+    dispatch = [s for s in rec.spans if s.name == "fel.dispatch"]
+    assert [(s.attrs["layout"], s.attrs["clients_in_flight"])
+            for s in dispatch] == [("vmap", 40)]
+    counters = rec.metrics_snapshot()["counters"]
+    assert counters["fel.dispatches"] == 1
+    assert "fel.sequential_dispatches" not in counters
+
+
+def test_engine_holds_shapes_and_history_keeps_the_newest_gw():
+    """The engine keeps the parameters' shapes and dtypes, no values; the
+    runtime's history keeps gw(k) for the newest round only, and every
+    round's block keeps its digest."""
+    import hashlib
+    import jax
+    train, test = make_mnist_like(n_train=240, n_test=20)
+    cfg = BHFLConfig(n_nodes=2, clients_per_node=2, fel_iterations=1,
+                     mlp=MLPConfig(hidden=8), engine="batched")
+    rt = BHFLRuntime(build_hierarchy(train, 2, 2, "iid"), cfg, test)
+    leaves = jax.tree.leaves(rt._engine._template)
+    assert leaves and all(isinstance(a, jax.ShapeDtypeStruct) for a in leaves)
+    gws = []
+    for _ in range(3):
+        gws.append(np.asarray(rt.run_round().consensus.global_model,
+                              np.float32))
+    assert [m.consensus.global_model is None for m in rt.history] == [
+        True, True, False]
+    np.testing.assert_array_equal(_global_flat(rt), gws[-1])
+    blocks = rt.consensus.ledgers[0].blocks
+    assert [b.global_model_digest for b in blocks] == [
+        hashlib.sha256(gw.tobytes()).hexdigest() for gw in gws]

@@ -550,8 +550,7 @@ def test_device_module_names_are_stable():
     eng = rt._engine
     idx, seeds = eng._prep(1)
     lowered = eng._round_fn.lower(rt._global_flat, idx, seeds, eng._data,
-                                  eng._sizes_f, eng._bs_dev, eng._stepmask,
-                                  eng._template)
+                                  eng._sizes_f, eng._bs_dev, eng._stepmask)
     assert module(lowered) == "jit_round_fn"
     W = jnp.ones((3, 16), jnp.float32)
     assert module(model_evaluation.lower(W, jnp.ones(3))) == \

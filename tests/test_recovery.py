@@ -183,3 +183,73 @@ def test_rejoin_ledger_adopts_best_reachable_chain():
     # already caught up: nothing to adopt, and no peers is a no-op
     assert rejoin_ledger(behind, [cons.ledgers[1]], cons.public_keys) == 0
     assert rejoin_ledger(behind, [], cons.public_keys) == 0
+
+
+# ---------------------------------------------------------------------------
+# A commit's model in a memory-only WAL
+# ---------------------------------------------------------------------------
+
+def _models(n=3, d=64, seed=0):
+    rng = np.random.default_rng(seed)
+    return [np.asarray(rng.normal(size=d), np.float32) for _ in range(n)]
+
+
+def test_pathless_wal_holds_the_commit_bytes_and_no_hex():
+    wal = NodeWAL(1)
+    node = HCDSNode(1, wal=wal)
+    model = b"\x01" * 1000
+    node.commit(None, round=0, model_bytes=model)
+    rec = wal.lookup("commit", 0)
+    assert "model" not in rec.data
+    assert all(len(v) < 200 for v in rec.data.values())
+    assert wal.commit_model(rec) is model            # the object, no copy
+    assert wal.held_bytes == 1000
+
+
+def test_durable_wal_still_writes_the_model_into_its_file(tmp_path):
+    wal = NodeWAL(1, path=tmp_path / "n1.wal")
+    node = HCDSNode(1, wal=wal)
+    node.commit(None, round=0, model_bytes=b"model-A")
+    again = NodeWAL(1, path=tmp_path / "n1.wal")
+    rec = again.lookup("commit", 0)
+    assert rec.data["model"] == b"model-A".hex()
+    assert again.commit_model(rec) == b"model-A" and again.held_bytes == 0
+
+
+def test_wal_releases_the_model_once_the_block_is_on_the_ledger():
+    from repro import obs
+    cons = PoFELConsensus(n_nodes=3)
+    rec = obs.TraceRecorder("t")
+    with obs.use_recorder(rec):
+        cons.run_round(_models(), [1.0, 1.0, 1.0])
+    assert all(w.held_bytes == 0 for w in cons.wals.values())
+    assert all(w.lookup("commit", 0) is not None for w in cons.wals.values())
+    held = [s.attrs["held_bytes"] for s in rec.spans
+            if s.name == "wal.log" and s.attrs.get("kind") == "commit"]
+    assert len(held) == 3 and held[0] > 0
+    # the commit record still refuses a conflicting re-commit
+    with pytest.raises(WALConflict):
+        cons.hcds_nodes[0].commit(None, round=0, model_bytes=b"other")
+    # the nodes keep the newest minted round's models, none older
+    node = cons.hcds_nodes[1]
+    assert len(node.accepted_models(0)) == 3
+    cons.run_round(_models(seed=1), [1.0, 1.0, 1.0])
+    assert node.accepted_models(0) == {} and 0 not in node._own
+    assert len(node.accepted_models(1)) == 3
+
+
+def test_crash_before_reveal_replays_byte_identical_with_held_bytes():
+    wal = NodeWAL(2)
+    node = HCDSNode(2, wal=wal)
+    model = bytes(range(256)) * 4
+    c = node.commit(None, round=5, model_bytes=model)
+    wipe_volatile(node)                              # crash before reveal
+    assert replay_wal(node, wal) == 1
+    assert node._commits[5][2] == c
+    r = node.reveal(5)
+    assert r.model_bytes == model
+    assert crypto.sha256_digest(r.nonce, r.model_bytes) == c.digest
+    # after the round's block, the payload is gone and replay skips it
+    wal.release_model(5)
+    wipe_volatile(node)
+    assert replay_wal(node, wal) == 0 and wal.held_bytes == 0

@@ -65,7 +65,7 @@ def test_batched_fel_round_compiles_for_v5e(one_chip):
     eng = rt._engine
     idx, seeds = eng._batch_plan(round_seed=1)
     args = (rt._global_flat, idx, seeds.astype("int32"), eng._data,
-            eng._sizes_f, eng._bs_dev, eng._stepmask, eng._template)
+            eng._sizes_f, eng._bs_dev, eng._stepmask)
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
