@@ -107,7 +107,8 @@ class HCDSNode:
         # received commitments / accepted reveals per round
         self._commits: Dict[int, Dict[int, Commitment]] = {}
         self._reveals: Dict[int, Dict[int, Reveal]] = {}
-        self._own: Dict[int, tuple[bytes, bytes]] = {}  # round -> (nonce, model_bytes)
+        # round -> (nonce, model_bytes, H(nonce‖model_bytes))
+        self._own: Dict[int, tuple[bytes, bytes, bytes]] = {}
         # round -> node_id -> commitment record index. Precedence between
         # identical reveals is decided by this order (§4.1: the commitment
         # stage, not reveal arrival, fixes who owns a model). Drivers call
@@ -117,12 +118,16 @@ class HCDSNode:
 
     # -- commit stage -----------------------------------------------------
     def commit(self, model: Any, round: int,
-               model_bytes: Optional[bytes] = None) -> Commitment:
+               model_bytes: Optional[bytes] = None,
+               model_digest: Optional[bytes] = None) -> Commitment:
         """Alg. 2 lines 1-4: build this node's commitment for ``round``.
 
         ``model_bytes`` lets the caller hand in the already-serialized
         model so one round serializes each model exactly once (the driver
-        reuses the same bytes for the block's model digests).
+        reuses the same bytes for the block's model digests), and
+        ``model_digest`` its sha256, which the WAL keys the commit by: one
+        round hashes each model's bytes alone once, for the WAL and the
+        block both.
         """
         if model_bytes is None:
             model_bytes = serialize_pytree(model)
@@ -130,7 +135,7 @@ class HCDSNode:
             # already committed for this round (pre-crash)? Re-issue the
             # logged statement byte-for-byte instead of double-signing; a
             # *different* model for the same round raises WALConflict
-            rec = self.wal.commit_record(round, model_bytes)
+            rec = self.wal.commit_record(round, model_bytes, model_digest)
             if rec is not None:
                 return self.restore_own_commit(
                     round, nonce=bytes.fromhex(rec.data["nonce"]),
@@ -143,8 +148,8 @@ class HCDSNode:
                                   self.keypair.private_key)
         if self.wal is not None:
             self.wal.log_commit(round, model_bytes, nonce, digest,
-                                env.signature)
-        self._own[round] = (nonce, model_bytes)
+                                env.signature, model_digest)
+        self._own[round] = (nonce, model_bytes, digest)
         c = Commitment(self.node_id, round, digest, env.signature)
         # record own commit (self-signed just now — no re-verification)
         self.receive_commit(c, self.keypair.public_key, verified=True)
@@ -155,8 +160,9 @@ class HCDSNode:
                            tag: crypto.Signature) -> Commitment:
         """Recovery path (``repro.core.recovery.replay_wal``): reinstate
         this node's own already-signed commitment after a restart, without
-        fresh signing. Idempotent."""
-        self._own[round] = (nonce, model_bytes)
+        fresh signing. Idempotent. ``digest`` is the logged H(r‖w) over
+        exactly this (nonce, model_bytes)."""
+        self._own[round] = (nonce, model_bytes, digest)
         c = Commitment(self.node_id, round, digest, tag)
         self.receive_commit(c, self.keypair.public_key, verified=True)
         return c
@@ -215,8 +221,12 @@ class HCDSNode:
 
     # -- reveal stage ------------------------------------------------------
     def reveal(self, round: int) -> Reveal:
-        """Alg. 2 line 11: broadcast (r, w, tag)."""
-        nonce, model_bytes = self._own[round]
+        """Alg. 2 line 11: broadcast (r, w, tag). The node's own store
+        checks it with the H(r‖w) it computed over these very (r, w) at
+        commit, not with the recorded commitment: after two commits in one
+        round the store keeps the first, and the reveal of the second must
+        still fail to bind."""
+        nonce, model_bytes, digest = self._own[round]
         c = self._commits[round][self.node_id]
         if self.wal is not None:
             # reveal-sent record: conflicts are impossible while commits
@@ -224,7 +234,7 @@ class HCDSNode:
             # issued so a restarted node re-broadcasts, never re-derives
             self.wal.log_reveal(round, c.digest)
         r = Reveal(self.node_id, round, nonce, model_bytes, c.tag)
-        self.receive_reveal(r, self.keypair.public_key)
+        self.receive_reveal(r, self.keypair.public_key, digest=digest)
         return r
 
     def receive_reveal(self, r: Reveal, sender_pk: crypto.Point,
@@ -291,13 +301,15 @@ class HCDSNode:
 def run_hcds_round(nodes: list[HCDSNode], models: list[Any], round: int,
                    public_keys: Optional[dict[int, crypto.Point]] = None,
                    model_bytes: Optional[list[bytes]] = None,
+                   model_digests: Optional[list[bytes]] = None,
                    ) -> dict[int, dict[int, HCDSResult]]:
     """Drive one full commit+reveal exchange among honest ``nodes``.
 
     Returns {receiver_id: {sender_id: result}} for the reveal stage.
 
     Each model is serialized exactly once per round (the per-sender bytes
-    are computed up front, or taken from ``model_bytes``), and signature
+    are computed up front, or taken from ``model_bytes``, with their
+    sha256 from ``model_digests`` for the WAL when given), and signature
     verification happens once per phase: all commit envelopes go through a
     single ``verify_envelopes`` batch instead of a dverify per
     (sender, receiver) pair, and each reveal is hashed once with the digest
@@ -306,11 +318,14 @@ def run_hcds_round(nodes: list[HCDSNode], models: list[Any], round: int,
     pks = public_keys or {n.node_id: n.keypair.public_key for n in nodes}
     if model_bytes is None:
         model_bytes = [serialize_pytree(m) for m in models]
+    if model_digests is None:
+        model_digests = [None] * len(nodes)
     rec = get_recorder()
     with rec.span("hcds:commit_stage", cat="hcds", round=round,
                   n_nodes=len(nodes)):
-        commits = [n.commit(m, round, model_bytes=b)
-                   for n, m, b in zip(nodes, models, model_bytes)]
+        commits = [n.commit(m, round, model_bytes=b, model_digest=h)
+                   for n, m, b, h in zip(nodes, models, model_bytes,
+                                         model_digests)]
         batch = verify_envelopes([c.envelope for c in commits], pks)
         if not batch.ok:
             forged = batch.bad_senders([c.envelope for c in commits])

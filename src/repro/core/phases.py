@@ -160,16 +160,18 @@ class CommitReveal(ConsensusPhase):
         # the host's first read of W(k): under tracing, the FEL program's
         # device time lands here rather than inside the first serialize
         device_wait("W", ctx.models)
-        # serialize each model once; HCDS commits and the block's model
-        # digests (BlockMint) both reuse these bytes
+        # serialize and hash each model once; HCDS commits (the WAL's key)
+        # and the block's model digests (BlockMint) both reuse these
         model_bytes = [serialize_pytree(m) for m in ctx.models]
-        ctx.extra["model_bytes"] = model_bytes
+        model_digests = [crypto.sha256_digest(b) for b in model_bytes]
+        ctx.extra["model_digests"] = model_digests
         if ctx.env is not None:
-            self._run_networked(ctx, model_bytes)
+            self._run_networked(ctx, model_bytes, model_digests)
             return
         reveal_results = run_hcds_round(self.nodes, ctx.models, ctx.round,
                                         self.public_keys,
-                                        model_bytes=model_bytes)
+                                        model_bytes=model_bytes,
+                                        model_digests=model_digests)
         for recv, senders in reveal_results.items():
             for sender, res in senders.items():
                 if not res.accepted and sender not in ctx.rejected:
@@ -185,8 +187,8 @@ class CommitReveal(ConsensusPhase):
                                              round=ctx.round,
                                              node=res.evicted)
 
-    def _run_networked(self, ctx: RoundContext,
-                       model_bytes: List[bytes]) -> None:
+    def _run_networked(self, ctx: RoundContext, model_bytes: List[bytes],
+                       model_digests: List[bytes]) -> None:
         env = ctx.env
         alive = env.alive()
         commits = {}
@@ -196,7 +198,8 @@ class CommitReveal(ConsensusPhase):
                 env.note("commit_withheld", round=ctx.round, node=i)
                 continue
             c = self.nodes[i].commit(ctx.models[i], ctx.round,
-                                     model_bytes=model_bytes[i])
+                                     model_bytes=model_bytes[i],
+                                     model_digest=model_digests[i])
             commits[i] = env.mutate_commit(i, c)
         # one batch verification of the phase's commit envelopes — the
         # sender set is shared by every receiver, so N×(N−1) per-message
@@ -243,8 +246,9 @@ class CommitReveal(ConsensusPhase):
                     continue
                 if not env.execute_crash(spec, i):
                     continue        # still down: nothing to re-broadcast
-                late[i] = self.nodes[i].commit(ctx.models[i], ctx.round,
-                                               model_bytes=model_bytes[i])
+                late[i] = self.nodes[i].commit(
+                    ctx.models[i], ctx.round, model_bytes=model_bytes[i],
+                    model_digest=model_digests[i])
             if late:
                 late_senders = sorted(late)
                 late_batch = verify_envelopes(
@@ -574,15 +578,15 @@ class BlockMint(ConsensusPhase):
     def _mint(self, ctx: RoundContext, leader: int,
               votes: Dict[int, int]) -> Block:
         n = ctx.n_nodes
-        # reuse the bytes CommitReveal already serialized (one
-        # serialization per model per round); fall back if the pipeline
-        # was rearranged without a CommitReveal stage
-        model_bytes = ctx.extra.get("model_bytes")
-        if model_bytes is None or len(model_bytes) != len(ctx.models):
-            model_bytes = [serialize_pytree(m) for m in ctx.models]
+        # reuse the digests CommitReveal already computed (one
+        # serialization and one sha256 per model per round); fall back if
+        # the pipeline was rearranged without a CommitReveal stage
+        hashes = ctx.extra.get("model_digests")
+        if hashes is None or len(hashes) != len(ctx.models):
+            hashes = [crypto.sha256_digest(serialize_pytree(m))
+                      for m in ctx.models]
         avail = ctx.available if ctx.available is not None else list(range(n))
-        model_digests = {i: crypto.sha256_digest(model_bytes[i]).hex()
-                         for i in avail}
+        model_digests = {i: hashes[i].hex() for i in avail}
         rec = get_recorder()
         with rec.span("device.get", on="gw") as pull:
             gw_bytes = np.asarray(ctx.global_model, np.float32).tobytes()

@@ -64,6 +64,14 @@ def _texts_equal(a: str, b: str) -> bool:
     return hmac.compare_digest(a.encode(), b.encode())
 
 
+def _model_key(model_bytes: bytes, model_digest: Optional[bytes]) -> str:
+    """A commit record's key: sha256(model_bytes), hashed only when the
+    caller does not hand it in."""
+    if model_digest is None:
+        model_digest = crypto.sha256_digest(model_bytes)
+    return model_digest.hex()
+
+
 @dataclass(frozen=True)
 class WALRecord:
     """One durable protocol statement: ``digest`` is the conflict key for
@@ -168,10 +176,12 @@ class NodeWAL:
 
     # -- typed helpers for the four protocol statements ----------------------
     def log_commit(self, round: int, model_bytes: bytes, nonce: bytes,
-                   digest: bytes, tag: crypto.Signature) -> WALRecord:
+                   digest: bytes, tag: crypto.Signature,
+                   model_digest: Optional[bytes] = None) -> WALRecord:
         """Record a commit-sent: keyed by the *model* digest (two commits
         to the same model differ only in nonce and are not equivocation —
-        two commits to different models are). A file-backed log writes
+        two commits to different models are), ``model_digest`` when the
+        caller already holds sha256(model_bytes). A file-backed log writes
         the model into the record as hex; a memory-only one keeps the
         ``bytes`` object itself beside the record."""
         data = dict(nonce=nonce.hex(), commitment=digest.hex(),
@@ -179,7 +189,7 @@ class NodeWAL:
         if self.path is not None:
             data["model"] = model_bytes.hex()
         return self._log("commit", round,
-                         crypto.sha256_digest(model_bytes).hex(),
+                         _model_key(model_bytes, model_digest),
                          held=None if self.path is not None else model_bytes,
                          **data)
 
@@ -196,16 +206,18 @@ class NodeWAL:
         stays, and still refuses a conflicting re-commit."""
         self._models.pop(int(round), None)
 
-    def commit_record(self, round: int,
-                      model_bytes: bytes) -> Optional[WALRecord]:
+    def commit_record(self, round: int, model_bytes: bytes,
+                      model_digest: Optional[bytes] = None,
+                      ) -> Optional[WALRecord]:
         """The logged commit for ``round``, or None. Raises
         :class:`WALConflict` if one exists for *different* model bytes —
-        the double-sign the WAL exists to prevent."""
+        the double-sign the WAL exists to prevent. ``model_digest`` is
+        sha256(model_bytes) when the caller already holds it."""
         rec = self.lookup("commit", round)
         if rec is None:
             return None
         if not _texts_equal(rec.digest,
-                            crypto.sha256_digest(model_bytes).hex()):
+                            _model_key(model_bytes, model_digest)):
             raise WALConflict(
                 f"node {self.node_id}: commit for round {round} already "
                 f"logged over different model bytes — refusing the "

@@ -496,7 +496,13 @@ def test_round_work_spans_nest_under_their_phases_and_count_bytes(
     assert counters["transfer.h2d_bytes"] == (test_bytes
                                               + prep.attrs["h2d_bytes"]
                                               + btsv_put.attrs["h2d_bytes"])
-    assert counters["crypto.sha256_bytes"] >= 4 * n * d * 4
+    # SHA-256 reads each model three times (commitment, the sha256(w) the
+    # WAL and the block share, the receivers' verification) and gw once;
+    # the rest is nonces and envelope, vote and block digests
+    from repro.core.serialization import serialize_pytree
+    model_len = len(serialize_pytree(run.runtime.global_params))
+    small = counters["crypto.sha256_bytes"] - (3 * n * model_len + d * 4)
+    assert 0 <= small < 64 * 1024, small
 
 
 def test_btsv_tally_crosses_to_the_device_once_each_way(traced_round):
