@@ -10,7 +10,7 @@ Run:  PYTHONPATH=src python examples/attack_simulation.py
 
 The CI-enforced versions of these assertions live in
 ``tests/test_attacks.py``; the scenario registry and the report schema
-are documented in ``benchmarks/README.md`` ("The repro.sim scenario
+are documented in ``README.md`` ("The ``repro.sim`` scenario
 registry"). Add your own attacks by registering a ``sim.Scenario`` with
 adversaries from ``repro.sim.adversary``.
 """

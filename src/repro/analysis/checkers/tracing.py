@@ -1,8 +1,7 @@
 """RA3xx — JAX tracing hygiene.
 
-The FEL engine compiles whole rounds (`fl.batched_fel`), the crypto limb
-backend jits the RLC batch equation, and the shape-bucketing caches key
-compiled programs on static arguments. Tracing-hostile Python inside any
+The FEL engine compiles whole rounds (`fl.batched_fel`), and the
+shape-bucketing caches key compiled programs on static arguments. Tracing-hostile Python inside any
 of those silently recompiles, diverges between traced and eager runs, or
 crashes at trace time:
 
@@ -20,11 +19,11 @@ RA303  static-argument hygiene. ``static_argnames``/``static_argnums``
        jit cache; jit-decorated functions with mutable default arguments
        hash-fail (or worse, alias) when treated static.
 
-RA304  unscoped float64. The limb crypto backend relies on *scoped*
-       ``jax.experimental.enable_x64`` contexts; a module-level
+RA304  unscoped float64. A module-level
        ``jax.config.update("jax_enable_x64", ...)`` flips the dtype of
        every array in the process (breaking the f32 FEL engine), and
-       ``jnp.float64`` outside such a scope silently downcasts.
+       ``jnp.float64`` outside a scoped ``enable_x64`` context silently
+       downcasts.
 """
 
 from __future__ import annotations

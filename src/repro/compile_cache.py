@@ -1,8 +1,7 @@
 """The one owner of JAX's persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``examples/*.py``, the crypto kernel
-cache) call :func:`enable` before their first compile; importing this
-module changes nothing.
+Entry points (``chip_smoke.py``, ``examples/*.py``) call :func:`enable`
+before their first compile; importing this module changes nothing.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and no other
   directory is set here.

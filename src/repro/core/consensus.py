@@ -9,7 +9,7 @@ One consensus round among N BCFL nodes, given their FEL models W(k):
   5. leader mints + signs the new block; every node verifies and appends
 
 ``PoFELConsensus`` is the host-side orchestrator used by the paper-faithful
-FL runtime and the benchmarks. It composes the five protocol phases from
+FL runtime and ``bench/``. It composes the five protocol phases from
 ``repro.core.phases`` (CommitReveal → ModelEvaluation → VoteCollection →
 Tally → BlockMint) over a typed ``RoundContext``; swap or hook individual
 phases instead of overriding ``run_round``. The in-graph sharded ME used

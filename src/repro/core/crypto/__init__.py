@@ -9,8 +9,7 @@ a dependency-free implementation of both:
   style, HMAC-DRBG) ECDSA over secp256k1.
 * ``verify_batch`` — round-level verification of many (tag, PK, digest)
   triples at once, behind a pluggable backend seam
-  (``set_backend("naive" | "windowed" | "batch" | "glv" | "jax" |
-  "auto")``).
+  (``set_backend("naive" | "batch" | "glv")``).
 
 The ``batch`` backend (the default) verifies a whole phase's envelopes with
 one randomized-linear-combination equation: per signature it recovers the
@@ -31,24 +30,13 @@ Package layout (the point-arithmetic hot loop lives below the seam):
 * ``curve``  — secp256k1 in Jacobian coordinates: add/double with no
   per-op inversion, window tables built with one batched inversion, and
   the GLV + wNAF/Pippenger multi-scalar engine (``msm_jc``) behind the
-  batch equation (plus the affine legacy ops the benchmarks keep as the
-  pre-Jacobian baseline);
-* ``backends.python`` — the ``CurveOps`` seam and the naive / windowed /
-  batch / glv backends;
-* ``backends.jax`` — the limb-vectorized JAX backend: field elements as
-  8×32-bit limbs in uint64 lanes, the whole RLC batch equation as one
-  jitted GLV multi-scalar program over all deduplicated signatures;
-* ``aotcache`` — on-disk ``jax.export`` kernel blobs, beside the
-  persistent XLA compilation cache of ``repro.compile_cache``, so the jax
-  backend's multi-second compile is paid once per install instead of once
-  per process.
+  batch equation (plus the affine ops the curve tests pin the Jacobian
+  formulas against);
+* ``backends.python`` — the ``CurveOps`` seam and the naive / batch / glv
+  backends.
 
-The Python backends run in the *host control plane* of the framework: the
-TPU training graph never hashes or signs. The ``jax`` backend moves the
-round-level batch equation onto the same JAX substrate as the FEL engine
-(still CPU-hosted by default — there is no MXU/VPU analogue of
-carry-chain crypto), so deployments that colocate consensus with
-accelerators can fold verification into the device program stream.
+All of it runs in the *host control plane* of the framework, in CPython
+big integers: the TPU training graph never hashes or signs.
 """
 
 from __future__ import annotations
@@ -57,108 +45,52 @@ import contextlib
 import hashlib
 import hmac
 import os
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 from repro.core.crypto import curve, field
 from repro.core.crypto.backends.python import (BatchOps, CurveOps, GLVOps,
-                                               NaiveOps, WindowedOps,
-                                               rlc_coefficient)
+                                               NaiveOps)
 from repro.obs import get_recorder, spanned
 
-# ---------------------------------------------------------------------------
-# Back-compat re-exports: the pre-package module exposed these names, and
-# tests/benchmarks/experiments reach for them.
-# ---------------------------------------------------------------------------
-_P = field.P
-_N = curve.N
-_GX = curve.GX
-_GY = curve.GY
-_A = curve.A
-
 Point = curve.Point
-_INF = curve.INF
+_N = curve.N
 _is_inf = curve.is_inf
 _inv_mod = field.inv_mod
-_point_add = curve.affine_point_add
-_point_mul_naive = curve.point_mul_naive
-_strauss_shamir = curve.strauss_shamir
-_multi_scalar = curve.multi_scalar
-
-WindowTable = curve.WindowTable
-_WINDOW_BITS = curve._WINDOW_BITS
-_WINDOW_MASK = curve._WINDOW_MASK
-_N_WINDOWS = curve._N_WINDOWS
-_build_window_table = curve.build_window_table
-_point_mul_windowed = curve.point_mul_windowed
-_g_table = curve.g_table
-_pk_table = curve.pk_table
-_PK_TABLES = curve._PK_TABLES
-_rlc_coefficient = rlc_coefficient
-
-
-def _point_mul(k: int, p: Point) -> Point:
-    """Scalar multiplication; routes G through the precomputed base-point
-    window table, everything else through plain double-and-add."""
-    if p == curve.G:
-        return curve.point_mul_windowed(k, curve.g_table())
-    return curve.point_mul_naive(k, p)
 
 
 # ---------------------------------------------------------------------------
 # Backend seam
 # ---------------------------------------------------------------------------
-# "naive"    — double-and-add everywhere: the pre-optimization baseline.
-# "windowed" — 4-bit fixed-window tables (G precomputed, per-PK cached):
-#              the per-message fast path.
-# "batch"    — per-message verification identical to "windowed", but
-#              ``verify_batch`` additionally folds a whole phase's tags into
-#              one randomized-linear-combination equation (GLV +
-#              wNAF/Pippenger MSM) with bisection fallback for attribution.
-# "glv"      — ``batch`` semantics with a uniform-operation-schedule
-#              fixed-base ladder on the signing side and the interleaved
-#              wNAF engine pinned for the equation.
-# "jax"      — ``batch`` semantics with the RLC equation evaluated by the
-#              limb-vectorized JAX kernel (``backends.jax``); requires jax.
-# set_backend("auto") runs a one-shot calibration probe and picks
-# between "batch" and "jax" (see _calibrate).
+# "naive" — double-and-add everywhere: the tests' reference.
+# "batch" — 4-bit fixed-window tables (G precomputed, per-PK cached) per
+#           message, and ``verify_batch`` folds a whole phase's tags into
+#           one randomized-linear-combination equation (GLV +
+#           wNAF/Pippenger MSM) with bisection fallback for attribution.
+# "glv"   — ``batch`` semantics with a uniform-operation-schedule
+#           fixed-base ladder on the signing side and the interleaved
+#           wNAF engine pinned for the equation.
 
-BACKENDS = ("naive", "windowed", "batch", "glv", "jax")
+_OPS: Dict[str, CurveOps] = {"naive": NaiveOps(), "batch": BatchOps(),
+                             "glv": GLVOps()}
+BACKENDS = tuple(_OPS)
 _BACKEND = "batch"
-_OPS: Dict[str, CurveOps] = {}
 
 
 def _get_ops(name: str) -> CurveOps:
-    """The ``CurveOps`` instance for a backend name (constructed lazily —
-    the jax backend imports jax only when first requested)."""
-    if name not in BACKENDS:
-        raise ValueError(f"unknown crypto backend {name!r}; "
-                         f"choose from {BACKENDS + ('auto',)}")
+    """The ``CurveOps`` instance for a backend name."""
     ops = _OPS.get(name)
     if ops is None:
-        if name == "jax":
-            from repro.core.crypto.backends.jax import JaxOps
-            ops = JaxOps()
-        else:
-            ops = {"naive": NaiveOps,
-                   "windowed": WindowedOps,
-                   "batch": BatchOps,
-                   "glv": GLVOps}[name]()
-        _OPS[name] = ops
+        raise ValueError(f"unknown crypto backend {name!r}; "
+                         f"choose from {BACKENDS}")
     return ops
 
 
 def set_backend(name: str) -> None:
-    """Select the crypto backend (``"naive" | "windowed" | "batch" |
-    "glv" | "jax" | "auto"``). Selecting ``"jax"`` on a jax-less install
-    raises; ``"auto"`` probes once and settles on "batch" or "jax"
-    (:func:`calibration_info` reports the decision)."""
+    """Select the crypto backend (``"naive" | "batch" | "glv"``)."""
     global _BACKEND
-    if name == "auto":
-        name = _calibrate()
-    _get_ops(name)          # validates the name and any gated dependency
+    _get_ops(name)          # validates the name
     _BACKEND = name
 
 
@@ -168,87 +100,13 @@ def get_backend() -> str:
 
 @contextlib.contextmanager
 def use_backend(name: str) -> Iterator[None]:
-    """Temporarily switch the crypto backend (benchmarks / tests)."""
+    """Temporarily switch the crypto backend (tests)."""
     prev = get_backend()
     set_backend(name)
     try:
         yield
     finally:
         set_backend(prev)
-
-
-# ---------------------------------------------------------------------------
-# Backend auto-calibration
-# ---------------------------------------------------------------------------
-
-_CALIBRATION: Optional[dict] = None
-
-
-def calibration_info() -> Optional[dict]:
-    """The decision record of the last ``set_backend("auto")`` probe, or
-    None if auto was never requested (recorded into BENCH_crypto.json by
-    the benchmark sweep)."""
-    return _CALIBRATION
-
-
-def _calibrate(probe_n: int = 16, force: bool = False) -> str:
-    """One-shot probe behind ``set_backend("auto")``.
-
-    The jax limb kernel only beats CPython big-ints when its compile cost
-    is already sunk, so the probe refuses to consider jax unless the AOT
-    kernel cache (``aotcache``) has serialized kernels for this jax
-    install — a cold probe would charge ~15 s of XLA compile to a
-    "cheap" calibration. With a warm cache each candidate verifies a
-    synthetic ``probe_n``-signature batch twice: the first call warms
-    per-key tables / loads the kernel (one-shot costs a long-running
-    round pipeline amortizes away), the second is timed and decides.
-    """
-    global _CALIBRATION
-    if _CALIBRATION is not None and not force:
-        return _CALIBRATION["chosen"]
-    info: dict = {"probe_n": probe_n, "chosen": "batch",
-                  "reason": "python batch default"}
-    try:
-        from repro.core.crypto import aotcache
-        import jax  # noqa: F401  (probe only makes sense with jax)
-        have_jax = True
-    except Exception as exc:  # pragma: no cover - jax-less installs
-        info["reason"] = f"jax unavailable ({type(exc).__name__})"
-        have_jax = False
-    if have_jax:
-        if not aotcache.has_cached_kernels():
-            info["reason"] = ("no AOT kernel cache — jax would pay a "
-                              "cold compile; run the bench sweep or "
-                              "python -m repro.core.crypto.aotcache "
-                              "--warm to populate it")
-        else:
-            items = [(dsign(sha256_digest(b"calib", bytes([i])), kp.private_key),
-                      kp.public_key, sha256_digest(b"calib", bytes([i])))
-                     for i, kp in ((j, ECDSAKeyPair.generate(b"calib%d" % j))
-                                   for j in range(probe_n))]
-            timings = {}
-            for cand in ("batch", "jax"):
-                try:
-                    if not _verify_batch_impl(items, backend=cand).ok:
-                        raise RuntimeError(f"{cand} rejected valid probe")
-                    # warm-up above paid the one-shot costs (table
-                    # builds, kernel load); the steady-state call decides
-                    t0 = time.perf_counter()
-                    ok = _verify_batch_impl(items, backend=cand).ok
-                    timings[cand] = time.perf_counter() - t0
-                    if not ok:  # pragma: no cover - defensive
-                        raise RuntimeError(f"{cand} rejected valid probe")
-                except Exception as exc:  # pragma: no cover - defensive
-                    info["reason"] = (f"probe failed on {cand} "
-                                      f"({type(exc).__name__})")
-                    timings = {}
-                    break
-            if timings:
-                info["probe_seconds"] = timings
-                info["chosen"] = min(timings, key=timings.get)
-                info["reason"] = "timed probe (AOT cache warm)"
-    _CALIBRATION = info
-    return info["chosen"]
 
 
 # ---------------------------------------------------------------------------
@@ -456,14 +314,13 @@ def verify_batch(items: Sequence[BatchItem],
                  backend: Optional[str] = None) -> BatchVerifyResult:
     """Verify many ``(tag, public_key, digest)`` triples at once.
 
-    Under the ``naive``/``windowed`` backends this is a plain loop of
-    :func:`dverify` calls (the per-message baseline, timed as such by the
-    benchmarks). Under ``batch``/``jax`` (equation-capable backends),
-    identical triples are deduplicated — one consensus round verifies each
-    sender's tag at N−1 receivers, so a round-level batch collapses
-    N×(N−1) checks to N — and the distinct remainder is checked with one
-    randomized-linear-combination equation (Jacobian Python or the JAX
-    limb kernel); on failure, bisection attributes the exact forged items.
+    Under the ``naive`` backend this is a plain loop of :func:`dverify`
+    calls (the per-message reference). Under ``batch``/``glv``
+    (equation-capable backends), identical triples are deduplicated — one
+    consensus round verifies each sender's tag at N−1 receivers, so a
+    round-level batch collapses N×(N−1) checks to N — and the distinct
+    remainder is checked with one randomized-linear-combination equation;
+    on failure, bisection attributes the exact forged items.
 
     The acceptance predicate is identical across backends: an item passes
     iff ``dverify`` passes it individually.

@@ -1,14 +1,9 @@
 """Curve-arithmetic backends behind the ``crypto.set_backend`` seam.
 
-``python`` hosts the three pure-Python backends (naive / windowed /
-batch); ``jax`` holds the limb-vectorized JAX backend and is imported
-lazily by ``crypto._get_ops`` so a jax-less install can still use every
-Python backend.
+``python`` hosts the three pure-Python backends (naive / batch / glv).
 """
 
 from repro.core.crypto.backends.python import (BatchOps, CurveOps, NaiveOps,
-                                               RLCItem, WindowedOps,
-                                               rlc_coefficient)
+                                               RLCItem)
 
-__all__ = ["CurveOps", "NaiveOps", "WindowedOps", "BatchOps", "RLCItem",
-           "rlc_coefficient"]
+__all__ = ["CurveOps", "NaiveOps", "BatchOps", "RLCItem"]

@@ -3,18 +3,17 @@
 ``repro.core.crypto`` routes every scalar-multiplication decision through
 one of these objects (selected by ``set_backend``):
 
-* :class:`NaiveOps`    — double-and-add, no tables: the algorithmic
-  baseline the benchmarks measure everything against.
-* :class:`WindowedOps` — 4-bit fixed-window tables (base point
-  precomputed, public keys cached FIFO): the per-message fast path.
-* :class:`BatchOps`    — per-message behaviour identical to windowed,
-  plus the round-level randomized-linear-combination equation
+* :class:`NaiveOps` — double-and-add, no tables: the reference the
+  tests check every other backend against.
+* :class:`BatchOps` — 4-bit fixed-window tables (base point precomputed,
+  public keys cached) for signing and single-message verification, plus
+  the round-level randomized-linear-combination equation
   (:meth:`rlc_check`) that ``verify_batch`` folds a whole phase's
   signatures through — evaluated by the GLV + wNAF/Pippenger MSM engine
   (``curve.msm_jc``).
-* :class:`GLVOps`      — BatchOps with a uniform-schedule fixed-base
-  ladder on the signing side (``curve.point_mul_base_ct``) and the
-  interleaved-wNAF engine pinned for the batch equation.
+* :class:`GLVOps` — BatchOps with a uniform-schedule fixed-base ladder on
+  the signing side (``curve.point_mul_base_ct``) and the interleaved-wNAF
+  engine pinned for the batch equation.
 
 All accumulate in Jacobian coordinates (``curve.py``): a point add costs
 mulmods instead of a modular inversion, and the RLC equation needs
@@ -39,16 +38,11 @@ from repro.obs import get_recorder
 RLCItem = Tuple[int, int, Point, Point]
 
 
-def rlc_coefficient() -> int:
-    """A fresh random 128-bit nonzero batch coefficient. 128 bits bound the
-    adversary's cancellation probability at 2^-128; fresh draws per equation
-    keep bisection sound against crafted forgery pairs."""
-    return int.from_bytes(os.urandom(16), "big") | 1
-
-
 def rlc_coefficients(n: int) -> List[int]:
-    """``n`` fresh coefficients from ONE urandom read — the per-draw
-    syscall is ~10 µs, which is real money across a 32-signature batch."""
+    """``n`` fresh random 128-bit nonzero batch coefficients from ONE
+    urandom read. 128 bits bound the adversary's cancellation probability
+    at 2^-128; fresh draws per equation keep bisection sound against
+    crafted forgery pairs."""
     buf = os.urandom(16 * n)
     return [int.from_bytes(buf[i:i + 16], "big") | 1
             for i in range(0, 16 * n, 16)]
@@ -89,8 +83,13 @@ class NaiveOps(CurveOps):
         return strauss_shamir(u1, G, u2, pk)
 
 
-class WindowedOps(CurveOps):
-    name = "windowed"
+class BatchOps(CurveOps):
+    name = "batch"
+    batch_equation = True
+    #: MSM engine for the batch equation — "auto" lets ``curve.msm_jc``
+    #: switch the fresh (−R) terms to Pippenger buckets past the
+    #: measured crossover; GLVOps pins "wnaf".
+    msm_engine = "auto"
 
     def mul_base(self, k: int) -> Point:
         return point_mul_windowed(k, g_table())
@@ -99,15 +98,6 @@ class WindowedOps(CurveOps):
         acc = jc_add(point_mul_windowed_jc(u1, g_table()),
                      point_mul_windowed_jc(u2, pk_table(pk)))
         return jc_to_affine(acc)
-
-
-class BatchOps(WindowedOps):
-    name = "batch"
-    batch_equation = True
-    #: MSM engine for the batch equation — "auto" lets ``curve.msm_jc``
-    #: switch the fresh (−R) terms to Pippenger buckets past the
-    #: measured crossover; GLVOps pins "wnaf".
-    msm_engine = "auto"
 
     def rlc_check(self, group: Sequence[RLCItem]) -> bool:
         rec = get_recorder()

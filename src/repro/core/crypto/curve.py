@@ -14,9 +14,9 @@ the cheapest add form); building a table runs in Jacobian and then
 normalizes all 64×15 entries with one :func:`field.batch_inv` call.
 
 The ``affine_*`` functions preserve the pre-Jacobian implementation:
-``benchmarks/bench_hcds.py`` times them as the PR-4 baseline the
-Jacobian/JAX backends are measured against, and the host-side backends
-use :func:`affine_point_add` for one-off sums where clarity beats speed.
+the curve tests pin the Jacobian formulas against them, and the host-side
+backends use :func:`affine_point_add` for one-off sums where clarity
+beats speed.
 """
 
 from __future__ import annotations
@@ -82,9 +82,9 @@ def affine_point_neg(p: Point) -> Point:
 
 
 def affine_point_mul_windowed(k: int, table: "WindowTable") -> Point:
-    """PR-4's windowed evaluation — one affine add (one inversion) per
-    nonzero 4-bit digit. Kept as the measured baseline for the Jacobian
-    rework; live code paths use :func:`point_mul_windowed`."""
+    """Windowed evaluation with one affine add (one inversion) per nonzero
+    4-bit digit. Kept as the reference the Jacobian window walk is pinned
+    against; live code paths use :func:`point_mul_windowed`."""
     acc = INF
     w = 0
     while k:
@@ -97,7 +97,8 @@ def affine_point_mul_windowed(k: int, table: "WindowTable") -> Point:
 
 
 def affine_multi_scalar(pairs: Sequence[Tuple[int, Point]]) -> Point:
-    """PR-4's shared-doubling Σ kᵢ·Pᵢ, affine adds throughout (baseline)."""
+    """Shared-doubling Σ kᵢ·Pᵢ, affine adds throughout (the reference
+    :func:`multi_scalar` is pinned against)."""
     pairs = [(k, p) for k, p in pairs if k and not is_inf(p)]
     if not pairs:
         return INF
@@ -370,8 +371,8 @@ def glv_decompose(k: int) -> Tuple[int, int]:
 # Python's signed big-int arithmetic keeps a*b % P exact for unreduced
 # operands, so the MSM hot loop elides the reductions whose only purpose
 # is keeping intermediates one limb small. ``jc_add_mixed``/``jc_double``
-# stay untouched: they are the PR-5 baseline the benchmarks measure
-# against and remain the live path for the naive/windowed backends.
+# stay untouched: they are the live path of the per-message ops of the
+# naive and batch backends.
 
 
 def _dbl(p: JPoint) -> JPoint:
@@ -471,7 +472,7 @@ def _signed_digits(k: int, c: int) -> List[int]:
 _MSM_W = 10  # window width for cached bases (G, public keys)
 _FRESH_W = 4  # window width for per-call bases (nonce points R): the
 # 128-bit RLC coefficients meet w=4's table-build + digit-add total
-# below w=5's (measured in BENCH_crypto.json — the 8-entry rows cost
+# below w=5's (measured on CPython big-ints — the 8-entry rows cost
 # more to build than their sparser digits save at these batch sizes)
 _GLV_SPLIT_BITS = 160  # decompose scalars longer than this
 
@@ -554,7 +555,7 @@ def msm_table(p: Point) -> MSMTable:
 
 # Below this many normalized fresh points the interleaved-wNAF chain wins;
 # above it the signed-bucket Pippenger's n/log(n) scaling takes over
-# (measured crossover on CPython big-ints; see benchmarks/README.md).
+# (measured crossover on CPython big-ints).
 PIPPENGER_MIN_FRESH = 128
 
 

@@ -38,9 +38,9 @@ def batch_inv(xs: Sequence[int], m: int = P) -> List[int]:
     3(N−1) multiplications + 1 inversion instead of N inversions.
 
     Zero entries are passed through as 0 (treated as "no inverse
-    requested" rather than an error): the JAX backend batch-normalizes
-    combination tables whose unused slots hold the point at infinity
-    (Z = 0), and skipping them here avoids a host-side filter pass.
+    requested" rather than an error), so a caller can batch-normalize a
+    table in which some slots hold the point at infinity (Z = 0) without
+    a filter pass first.
     """
     xs = [x % m for x in xs]
     if not xs:

@@ -312,9 +312,9 @@ register(Scenario(
     name="consortium_256",
     description="The scale run: 8 committees of 32 (N=256). Round "
                 "wall-time tracks the committee size (~N/K), not the "
-                "consortium (~N²) — the headline BENCH_consortium.json "
-                "measures; the report must show all-true per-committee "
-                "liveness and zero global safety violations.",
+                "consortium (~N²); the report must show all-true "
+                "per-committee liveness and zero global safety "
+                "violations.",
     rounds=4,
     n_nodes=256,
     clients_per_node=1,

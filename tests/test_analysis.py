@@ -682,7 +682,7 @@ def test_repo_gate_src_tests_is_clean():
     and no stale baseline entries."""
     baseline = load_baseline(os.path.join(REPO_ROOT,
                                           "analysis-baseline.json"))
-    report = analyze_paths(["src", "tests", "benchmarks"], root=REPO_ROOT,
+    report = analyze_paths(["src", "tests"], root=REPO_ROOT,
                            baseline=baseline)
     assert report.errors == []
     assert report.findings == [], [f"{f.path}:{f.line} {f.rule}"

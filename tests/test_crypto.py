@@ -5,6 +5,7 @@ import hashlib
 import pytest
 
 from repro.core import crypto
+from repro.core.crypto import curve, field
 
 
 def test_sha256_matches_hashlib():
@@ -56,7 +57,7 @@ def test_signature_deterministic_rfc6979():
 def test_public_key_on_curve():
     kp = crypto.ECDSAKeyPair.generate(b"x")
     x, y = kp.public_key
-    p = crypto._P
+    p = field.P
     assert (y * y - (x * x * x + 7)) % p == 0
 
 
@@ -67,9 +68,9 @@ def test_dsign_retry_signs_the_original_digest(monkeypatch):
     that s = z + r·priv ≡ 0 (mod n)."""
     digest = crypto.sha256_digest(b"retry me")
     z = crypto._bits2int(digest)
-    r = crypto._GX % crypto._N                  # k=1 → R = G
-    priv = (crypto._N - z) * crypto._inv_mod(r, crypto._N) % crypto._N
-    pub = crypto._point_mul(priv, (crypto._GX, crypto._GY))
+    r = curve.GX % curve.N                      # k=1 → R = G
+    priv = (curve.N - z) * field.inv_mod(r, curve.N) % curve.N
+    pub = curve.point_mul_naive(priv, curve.G)
 
     calls = []
     real = crypto._rfc6979_k

@@ -1,34 +1,28 @@
 """Differential backend parity for the secp256k1 point-arithmetic seam.
 
-Every backend — ``naive`` (Jacobian double-and-add), ``windowed``
-(fixed-window tables), ``batch`` (GLV + wNAF/Pippenger MSM behind the RLC
-batch equation), ``glv`` (same MSM forced onto the interleaved-wNAF
-engine), and ``jax`` (GLV limb-vectorized RLC kernel) — must agree with
-the single source of truth, the per-message ``dverify`` predicate, on
-every input shape: valid tags, forged tags, tampered recovery bits, and
-bare ``(r, s)`` pairs. Property-driven via the optional-hypothesis shim.
+Every backend — ``naive`` (Jacobian double-and-add), ``batch``
+(fixed-window tables per message, GLV + wNAF/Pippenger MSM behind the RLC
+batch equation) and ``glv`` (a uniform-schedule signing ladder, the same
+MSM forced onto the interleaved-wNAF engine) — must agree with the single
+source of truth, the per-message ``dverify`` predicate under ``naive``,
+on every input shape: valid tags, forged tags, tampered recovery bits,
+and bare ``(r, s)`` pairs. Property-driven via the optional-hypothesis
+shim.
 
 The curve layer is pinned separately: the Jacobian formulas (including
 the batched-inversion window-table build) must reproduce the affine
-baseline bit-for-bit — that equivalence is what makes the backend sweep
-in ``benchmarks/bench_hcds.py`` a fair before/after.
+formulas bit-for-bit.
 """
 
-import json
 import random
-from pathlib import Path
 
 import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import crypto
-from repro.core.crypto import curve
+from repro.core.crypto import curve, field
 
 BACKENDS = list(crypto.BACKENDS)
-try:                                    # the jax backend is dependency-gated
-    crypto._get_ops("jax")
-except Exception:                       # pragma: no cover - jax-less installs
-    BACKENDS.remove("jax")
 
 _KPS = [crypto.ECDSAKeyPair.generate(bytes([i, 0xBE])) for i in range(6)]
 
@@ -94,7 +88,7 @@ def test_verify_batch_parity_across_backends(n, mask, shape):
     mutated = [i for i in range(n) if (mask >> i) & 1]
     for i in mutated:
         items[i] = _mutate(items[i], shape)
-    with crypto.use_backend("windowed"):
+    with crypto.use_backend("naive"):
         individually = [crypto.dverify(t, pk, d) for t, pk, d in items]
     expected_bad = [i for i, ok in enumerate(individually) if not ok]
     if shape in _ACCEPTED:
@@ -221,7 +215,7 @@ def test_wnaf_digits_reconstruct(seed):
 
 def _msm_cases():
     pk0, pk1 = _KPS[0].public_key, _KPS[1].public_key
-    neg1 = (pk1[0], (-pk1[1]) % crypto._P)
+    neg1 = (pk1[0], (-pk1[1]) % field.P)
     big = curve.N - 2
     return [
         [],
@@ -343,22 +337,3 @@ def test_bisection_parity_with_pippenger_engine(monkeypatch):
     res = crypto.verify_batch(items, backend="batch")
     assert not res.ok and res.bad == (2, 5)
     assert crypto.verify_batch(_batch(5), backend="batch").ok
-
-
-# ---------------------------------------------------------------------------
-# benchmark headline pins (committed BENCH_crypto.json)
-# ---------------------------------------------------------------------------
-
-def test_bench_crypto_headline_pins():
-    """The committed sweep must carry this PR's acceptance numbers:
-    batch ≥2× over the PR-5 batch reconstruction at N=32, and a jax AOT
-    warm start (fresh process, serialized-kernel hit) under 1 s."""
-    path = (Path(__file__).resolve().parents[1]
-            / "benchmarks" / "BENCH_crypto.json")
-    data = json.loads(path.read_text())
-    t = data["target"]
-    assert t["measured_at_N32"] >= t["min_batch_speedup_vs_pr5_at_N32"]
-    assert data["point_backends"]["N32"]["batch_speedup_vs_pr5"] >= 2.0
-    warm = t["measured_jax_warm_start_s_at_l16"]
-    assert warm is not None and warm < t["max_jax_warm_start_s"]
-    assert data["calibration"]["chosen"] == data["default_backend"]
