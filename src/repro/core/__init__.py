@@ -50,6 +50,7 @@ _EXPORTS = {
     "unflatten_pytree": "repro.core.serialization",
     "unflatten_pytree_device": "repro.core.serialization",
     "serialize_pytree": "repro.core.serialization",
+    "serialize_pytrees": "repro.core.serialization",
     "MEResult": "repro.core.model_eval", "aggregate_global": "repro.core.model_eval",
     "cosine_similarities": "repro.core.model_eval",
     "flatten_model": "repro.core.model_eval",

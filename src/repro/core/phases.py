@@ -48,7 +48,7 @@ from repro.core.envelope import (commit_signing_digest, tags_equal,
 from repro.core.hcds import HCDSNode, run_hcds_round
 from repro.core.model_eval import (MEResult, make_predictions,
                                    model_evaluation_pytrees)
-from repro.core.serialization import serialize_pytree
+from repro.core.serialization import serialize_pytree, serialize_pytrees
 from repro.obs import device_nbytes, device_wait, get_recorder, spanned
 
 # (node_id, honest_vote, honest_predictions) -> (vote, predictions)
@@ -161,8 +161,9 @@ class CommitReveal(ConsensusPhase):
         # device time lands here rather than inside the first serialize
         device_wait("W", ctx.models)
         # serialize and hash each model once; HCDS commits (the WAL's key)
-        # and the block's model digests (BlockMint) both reuse these
-        model_bytes = [serialize_pytree(m) for m in ctx.models]
+        # and the block's model digests (BlockMint) both reuse these. Every
+        # row's pull is started before the first is packed.
+        model_bytes = serialize_pytrees(ctx.models)
         model_digests = [crypto.sha256_digest(b) for b in model_bytes]
         ctx.extra["model_digests"] = model_digests
         if ctx.env is not None:

@@ -13,13 +13,13 @@ encoding and the vector encoding in one module guarantees they agree.
 from __future__ import annotations
 
 import struct
-from typing import Any
+from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import device_nbytes, get_recorder
+from repro.obs import Recorder, device_nbytes, get_recorder
 
 _MAGIC = b"RPR0"
 
@@ -96,18 +96,44 @@ def serialize_pytree(tree: Any) -> bytes:
     """
     rec = get_recorder()
     if not rec.enabled:
-        return _serialize(tree)
-    # the pull of every device leaf to the host, and the packing
+        return _serialize(tree, rec)
+    # the wait for every device leaf's host value, and the packing
     with rec.span("serialize", cat="serialization",
                   d2h_bytes=device_nbytes(tree)):
-        return _serialize(tree)
+        return _serialize(tree, rec)
 
 
-def _serialize(tree: Any) -> bytes:
+def serialize_pytrees(trees: Sequence[Any]) -> list[bytes]:
+    """``[serialize_pytree(t) for t in trees]``, byte for byte, with the
+    host copy of every device leaf started before the first tree is
+    packed: the pulls of trees 1..N-1 run while tree 0 is packed.
+    Host (NumPy) leaves pack exactly as :func:`serialize_pytree` packs
+    them."""
+    trees = list(trees)
+    get_recorder().counter("serialize.prefetch_bytes", _prefetch(trees))
+    return [serialize_pytree(t) for t in trees]
+
+
+def _prefetch(trees: Sequence[Any]) -> int:
+    """Start the device-to-host copy of every ``jax.Array`` leaf whose
+    host value is not cached yet; the bytes started."""
+    started = 0
+    seen: set[int] = set()
+    for leaf in jax.tree.leaves(trees):
+        if (isinstance(leaf, jax.Array) and id(leaf) not in seen
+                and getattr(leaf, "_npy_value", None) is None):
+            seen.add(id(leaf))
+            leaf.copy_to_host_async()
+            started += leaf.nbytes
+    return started
+
+
+def _serialize(tree: Any, rec: Recorder) -> bytes:
     leaves = _sorted_leaves(tree)
+    with rec.span("serialize.pull", cat="serialization"):
+        arrays = [np.asarray(leaf) for _, leaf in leaves]
     out = [_MAGIC, struct.pack("<I", len(leaves))]
-    for path, leaf in leaves:
-        arr = np.asarray(leaf)
+    for (path, _), arr in zip(leaves, arrays):
         path_b = _keystr(path).encode()
         dtype_b = arr.dtype.str.encode()
         out.append(struct.pack("<I", len(path_b)))
@@ -116,8 +142,10 @@ def _serialize(tree: Any) -> bytes:
         out.append(dtype_b)
         out.append(struct.pack("<I", arr.ndim))
         out.append(struct.pack(f"<{arr.ndim}q", *arr.shape))
-        raw = np.ascontiguousarray(arr).tobytes()
-        out.append(struct.pack("<Q", len(raw)))
+        # a zero-copy byte view (a memoryview cannot export bfloat16), so
+        # the join below makes the only host copy of the leaf
+        raw = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+        out.append(struct.pack("<Q", raw.nbytes))
         out.append(raw)
     return b"".join(out)
 

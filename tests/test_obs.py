@@ -505,6 +505,31 @@ def test_round_work_spans_nest_under_their_phases_and_count_bytes(
     assert 0 <= small < 64 * 1024, small
 
 
+def test_serialize_starts_every_row_pull_before_packing(traced_round):
+    """CommitReveal serialises the N rows in one batch: every row's host
+    copy is started first (``serialize.prefetch_bytes``), and each row
+    keeps its own ``serialize`` span with its bytes, split by one
+    ``serialize.pull`` child into the wait for the row and the packing."""
+    import numpy as np
+
+    from repro.core.serialization import serialize_pytrees
+    rec, run, _ = traced_round
+    n, d = 3, run.runtime._global_flat.size
+    rows = _named(rec, "serialize")
+    assert len(rows) == n
+    pulls = _named(rec, "serialize.pull")
+    assert sorted(p.parent for p in pulls) == sorted(s.span_id for s in rows)
+    assert all("d2h_bytes" not in p.attrs for p in pulls)
+    assert run.obs["counters"]["serialize.prefetch_bytes"] == n * d * 4
+
+    host = obs.TraceRecorder("host")
+    with obs.use_recorder(host):
+        serialize_pytrees([{"w": np.ones(4, np.float32)}] * 2)
+    counters = host.metrics_snapshot()["counters"]
+    assert counters.get("serialize.prefetch_bytes", 0) == 0
+    assert counters.get("transfer.d2h_bytes", 0) == 0
+
+
 def test_btsv_tally_crosses_to_the_device_once_each_way(traced_round):
     """The tally uploads its votes and predictions in one transfer and
     pulls its whole result in one, both inside ``btsv.tally``; the block
